@@ -49,9 +49,7 @@ def main():
         )
         rows.append((name, analysis.roofline(co, mesh.devices.size, 0.0)))
 
-    from repro.distributed.sharding import shard_map
-
-    shard_grad = shard_map(
+    shard_grad = jax.shard_map(
         grad_fn,
         mesh=mesh,
         in_specs=(P(), P(("data", "model"))),

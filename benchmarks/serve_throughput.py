@@ -45,6 +45,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
 import sys
 import time
 
@@ -58,6 +59,7 @@ from repro.core.attention import (
     ragged_attention_flops,
     ragged_attention_hbm_bytes,
 )
+from repro.launch.compile_cache import place_compile_cache
 from repro.launch.mesh import make_local_mesh, make_mesh, make_pages_mesh
 from repro.launch.serve import DisaggRouter, Request, ServeLoop
 from repro.models import model as M
@@ -297,10 +299,9 @@ def main() -> None:
                          "resident pages must stay within "
                          "ceil(replicated peak / 4) + slack (the balanced "
                          "allocator bound), and both pools must drain at "
-                         "close().  Shards the DEVICE pool too when the "
-                         "host exposes >= 4 devices (set XLA_FLAGS="
-                         "--xla_force_host_platform_device_count=4); falls "
-                         "back to host-side-only shard accounting otherwise "
+                         "close().  Needs a multiple of 4 devices (set "
+                         "XLA_FLAGS=--xla_force_host_platform_device_count=4 "
+                         "on a CPU host) and fails otherwise "
                          "(deterministic sub-benchmark; emits the "
                          "shard_capacity BENCH section)")
     ap.add_argument("--check-quant", action="store_true",
@@ -318,6 +319,7 @@ def main() -> None:
     ap.add_argument("--json", default="BENCH_attention.json",
                     help="machine-readable output path ('' disables)")
     args = ap.parse_args()
+    place_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
 
     base = dataclasses.replace(registry.get(args.arch, reduced=True), dtype="float32")
     if args.scenario == "sliding_window":
@@ -1079,12 +1081,10 @@ def check_shard(cfg, mesh, params, *, impl: str, pattern: str):
 
     Reference: the single-loop paged engine over a REPLICATED pool on the
     plain data mesh.  Candidate: the :class:`DisaggRouter` (prefill worker +
-    decode worker, page-table handoff) over a 4-way page-sharded pool — on a
-    mesh with a ``pages`` axis when the host exposes a multiple of 4 devices
-    (CI sets ``XLA_FLAGS=--xla_force_host_platform_device_count=4``), else
-    host-side shard accounting over the replicated device pool (the
-    allocator's ranges and the capacity assertions are identical either
-    way; only the physical placement differs).
+    decode worker, page-table handoff) over a 4-way page-sharded pool on a
+    mesh with a ``pages`` axis.  The host must expose a multiple of 4
+    devices (CI sets ``XLA_FLAGS=--xla_force_host_platform_device_count=4``);
+    with fewer the gate fails rather than account shards on the host only.
 
     Deterministic assertions: (a) disagg generations token-identical to the
     single loop, (b) every shard's peak resident pages within
@@ -1092,6 +1092,12 @@ def check_shard(cfg, mesh, params, *, impl: str, pattern: str):
     for handoff-timing skew), (c) both engines' pools fully drained at
     ``close()``.  Returns (bench rows, failures)."""
     n_shards = 4
+    if jax.device_count() % n_shards:
+        return [], [
+            f"{impl}/{pattern}: --check-shard needs a multiple of {n_shards} "
+            f"devices, found {jax.device_count()} (set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={n_shards} on a CPU host)"
+        ]
     cache_len, chunk = 512, 32
     rng = np.random.default_rng(13)
     lens = [(int(rng.integers(20, 360)), int(rng.integers(2, 6)))
@@ -1122,14 +1128,9 @@ def check_shard(cfg, mesh, params, *, impl: str, pattern: str):
         rep_peak = rep.stats["pool_peak_pages"]
         rep_pool = rep.stats["pool_pages"]
 
-    device_sharded = jax.device_count() % n_shards == 0 and (
-        jax.device_count() >= n_shards
-    )
-    smesh = make_pages_mesh(n_shards) if device_sharded else mesh
     with DisaggRouter(
-        cfg, smesh, params, batch=3, prefill_batch=2, cache_len=cache_len,
-        chunk_size=chunk, pool_pages=rep_pool,
-        **({} if device_sharded else {"page_shards": n_shards}),
+        cfg, make_pages_mesh(n_shards), params, batch=3, prefill_batch=2,
+        cache_len=cache_len, chunk_size=chunk, pool_pages=rep_pool,
     ) as dis:
         t0 = time.perf_counter()
         done_d = dis.run(mk())
@@ -1166,7 +1167,6 @@ def check_shard(cfg, mesh, params, *, impl: str, pattern: str):
         "pattern": pattern,
         "cache_len": cache_len,
         "n_shards": n_shards,
-        "device_sharded": device_sharded,
         "devices": jax.device_count(),
         "pool_pages": dis.stats["pool_pages"],
         "replicated_peak_pages": rep_peak,
@@ -1182,7 +1182,7 @@ def check_shard(cfg, mesh, params, *, impl: str, pattern: str):
     }
     print(
         f"shard_capacity[{impl}/{pattern}]: {n_shards}-way "
-        f"{'device' if device_sharded else 'host'}-sharded pool, shard "
+        f"device-sharded pool, shard "
         f"peaks {shard_peaks} vs replicated {rep_peak} (bound {bound}), "
         f"{row['handoffs']} handoffs"
     )

@@ -18,7 +18,7 @@ Schemes (both symmetric, zero-point-free — attention rows are centred):
 * ``int8``:     ``scale = absmax / 127``, values rounded and clipped.
 * ``fp8_e4m3``: ``scale = absmax / 448`` (the e4m3 finite max), scaled cast —
   the mantissa keeps ~3 bits, the shared exponent headroom comes from the
-  scale.  Gated on the running jax exposing ``jnp.float8_e4m3fn``.
+  scale.
 * ``bf16``:     the unquantized passthrough — no scale leaves exist and every
   code path compiles the exact PR-9 graph (bit-identity is a test contract).
 """
@@ -32,7 +32,6 @@ __all__ = [
     "KV_DTYPES",
     "INT8_MAX",
     "FP8_MAX",
-    "fp8_supported",
     "validate_kv_dtype",
     "kv_store_dtype",
     "quantize_rows",
@@ -44,19 +43,10 @@ INT8_MAX = 127.0
 FP8_MAX = 448.0  # largest finite float8_e4m3fn
 
 
-def fp8_supported() -> bool:
-    return hasattr(jnp, "float8_e4m3fn")
-
-
 def validate_kv_dtype(kv_dtype: str) -> str:
     if kv_dtype not in KV_DTYPES:
         raise ValueError(
             f"kv_dtype must be one of {KV_DTYPES}, got {kv_dtype!r}"
-        )
-    if kv_dtype == "fp8_e4m3" and not fp8_supported():
-        raise ValueError(
-            "kv_dtype='fp8_e4m3' needs jnp.float8_e4m3fn, which this jax "
-            "build does not expose — use 'int8' (same byte width) or 'bf16'"
         )
     return kv_dtype
 
@@ -76,7 +66,7 @@ def _qmax(store_dtype) -> float:
     store_dtype = jnp.dtype(store_dtype)
     if store_dtype == jnp.dtype(jnp.int8):
         return INT8_MAX
-    if fp8_supported() and store_dtype == jnp.dtype(jnp.float8_e4m3fn):
+    if store_dtype == jnp.dtype(jnp.float8_e4m3fn):
         return FP8_MAX
     raise ValueError(f"no quantization scheme for store dtype {store_dtype}")
 

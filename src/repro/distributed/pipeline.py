@@ -17,8 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.sharding import shard_map
-
 __all__ = ["gpipe"]
 
 
@@ -56,7 +54,7 @@ def gpipe(
         # replicate the result: only the last stage holds nonzero values
         return jax.lax.psum(stacked, axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(axis), P()),

@@ -41,32 +41,8 @@ __all__ = [
     "init_tree",
     "abstract_tree",
     "sharding_tree",
-    "shard_map",
 ]
 
-
-def shard_map(f, mesh, in_specs, out_specs, axis_names=None):
-    """``jax.shard_map`` across jax versions.
-
-    >= 0.5 exposes it as ``jax.shard_map`` (with ``axis_names`` for partially
-    manual meshes); 0.4.x has ``jax.experimental.shard_map`` where the same
-    intent spells ``auto=`` (complement set) and requires ``check_rep=False``.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {} if axis_names is None else {"axis_names": set(axis_names)}
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as sm_legacy
-
-    kw: dict = {"check_rep": False}
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    out = sm_legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    if kw.get("auto"):
-        # 0.4.x: eager partially-auto shard_map is NotImplemented; jit is the
-        # supported path (a nested jit inlines when already traced)
-        out = jax.jit(out)
-    return out
 
 # logical axis -> candidate mesh axes (in priority order; all present ones used)
 RULES: dict[str | None, tuple[str, ...]] = {

@@ -77,8 +77,9 @@ def _prefill_kernel(
     scale: float, causal: bool, window: int | None, s_q: int, s_kv: int,
     q_tile: int, kv_tile: int, quantized: bool = False,
 ):
-    # quantized pools append per-row scale tiles after v: dequant happens here,
-    # right after the tile DMA, so the MXU math below is identical either way
+    # quantized pools append per-row scale tiles after v: the K scales
+    # multiply the score columns and the V scales the probabilities, so the
+    # dequant never touches the (tk, d) tiles
     if quantized:
         ksc_ref, vsc_ref, y_ref, m_ref, l_ref, acc_ref = refs
     else:
@@ -103,12 +104,11 @@ def _prefill_kernel(
         q = q_ref[0, 0].astype(jnp.float32) * scale  # (tq, d)
         k = k_ref[0].astype(jnp.float32)  # (tk, d)
         v = v_ref[0].astype(jnp.float32)
-        if ksc_ref is not None:
-            k = k * ksc_ref[0][:, None]
-            v = v * vsc_ref[0][:, None]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (tq, tk)
+        if ksc_ref is not None:  # per-key dequant scale, along the lanes
+            s = s * ksc_ref[0, 0]
 
         # fine mask: padded keys + causal diagonal + window edge inside the
         # (pattern-live) tile — block-level pruning already happened in the map
@@ -129,6 +129,8 @@ def _prefill_kernel(
         # and exp(s - m_new) would be 1, not 0
         p = jnp.where(mask, jnp.exp(s - m_new[:, :1]), 0.0)
         l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
+        if vsc_ref is not None:  # sum_j p_j (vs_j v_j) == sum_j (p_j vs_j) v_j
+            p = p * vsc_ref[0, 0]
         acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
             p, v, preferred_element_type=jnp.float32
         )
@@ -181,10 +183,13 @@ def mha_prefill(
     (:func:`repro.core.sparsity.translate_tables`).  Defaults to
     ``kv_index`` — the contiguous identity mapping.
 
-    ``k_scale`` / ``v_scale`` ((BK, Skv_pad) float32, or None): per-row
-    dequant scales of a QUANTIZED pool — the kernel reconstructs each K/V
-    tile right after its DMA (:mod:`repro.core.quant`); when None the call
-    compiles the exact unquantized graph."""
+    ``k_scale`` / ``v_scale`` ((Skv_pad / kv_tile, BK, 1, kv_tile) float32,
+    or None): per-row dequant scales of a QUANTIZED pool, one (1, kv_tile)
+    lane row per (tile, BK row) so every block's last two dims equal the
+    array's (:func:`repro.kernels.ops._scale_layout`).  The K scales multiply
+    the score columns and the V scales the probabilities
+    (:mod:`repro.core.quant`); when None the call compiles the exact
+    unquantized graph."""
     from jax.experimental.pallas import tpu as pltpu
 
     bk, g, sq_pad, d = q.shape
@@ -210,7 +215,7 @@ def mha_prefill(
     ]
     if quantized:
         sspec = pl.BlockSpec(
-            (1, kv_tile), lambda b, g, i, jj, kvi, lv, vt: (b, kvi[i, jj])
+            (1, 1, 1, kv_tile), lambda b, g, i, jj, kvi, lv, vt: (kvi[i, jj], b, 0, 0)
         )
         in_specs += [sspec, sspec]
         args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
@@ -515,12 +520,11 @@ def _decode_kernel_paged(
         q = q_ref[0, 0].astype(jnp.float32) * scale  # (gp, d)
         k = k_ref[0].astype(jnp.float32)  # (tk, d) — one physical page
         v = v_ref[0].astype(jnp.float32)
-        if ksc_ref is not None:  # dequantize the page in-register, post-DMA
-            k = k * ksc_ref[0][:, None]
-            v = v * vsc_ref[0][:, None]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (gp, tk)
+        if ksc_ref is not None:  # per-key dequant scale, along the lanes
+            s = s * ksc_ref[0, 0]
         # fine mask from VIRTUAL positions: the page holds virtual tile jv,
         # so its t-th row is absolute position jv*kv_tile + t
         kpos = jv * kv_tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -535,6 +539,8 @@ def _decode_kernel_paged(
         alpha = jnp.exp(m_prev[:, :1] - m_new[:, :1])
         p = jnp.where(valid, jnp.exp(s - m_new[:, :1]), 0.0)
         l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
+        if vsc_ref is not None:  # sum_j p_j (vs_j v_j) == sum_j (p_j vs_j) v_j
+            p = p * vsc_ref[0, 0]
         acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
             p, v, preferred_element_type=jnp.float32
         )
@@ -573,10 +579,10 @@ def mha_decode_paged(
     tiles (the fine mask's position base), ``step_live`` the packed liveness
     (:func:`repro.core.sparsity.translate_tables`).  ``cur_len`` (B,) is each
     row's live length in virtual token space; the grid never visits a dead or
-    unallocated tile.  ``k_scale`` / ``v_scale`` ((KV, n_pages * kv_tile)
+    unallocated tile.  ``k_scale`` / ``v_scale`` ((n_pages, KV, 1, kv_tile)
     float32, or None) carry a quantized pool's per-row dequant scales through
-    the SAME page indirection — the kernel reconstructs each page tile right
-    after its DMA.  Returns (B, KV, Gp, D)."""
+    the SAME page indirection (layout as in :func:`mha_prefill`).
+    Returns (B, KV, Gp, D)."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, kvh, gp, d = q.shape
@@ -602,7 +608,7 @@ def mha_decode_paged(
     ]
     if quantized:
         sspec = pl.BlockSpec(
-            (1, kv_tile), lambda b, h, jj, cl, kvi, vt, lv: (h, kvi[b, jj])
+            (1, 1, 1, kv_tile), lambda b, h, jj, cl, kvi, vt, lv: (kvi[b, jj], h, 0, 0)
         )
         in_specs += [sspec, sspec]
         args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
@@ -658,12 +664,11 @@ def _chunk_kernel_paged(
         q = q_ref[0, 0, 0].astype(jnp.float32) * scale  # (cp, d)
         k = k_ref[0].astype(jnp.float32)  # (tk, d) — one physical page
         v = v_ref[0].astype(jnp.float32)
-        if ksc_ref is not None:  # dequantize the page in-register, post-DMA
-            k = k * ksc_ref[0][:, None]
-            v = v * vsc_ref[0][:, None]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (cp, tk)
+        if ksc_ref is not None:  # per-key dequant scale, along the lanes
+            s = s * ksc_ref[0, 0]
 
         qpos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         kpos = jv * kv_tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -683,6 +688,8 @@ def _chunk_kernel_paged(
         alpha = jnp.exp(m_prev[:, :1] - m_new[:, :1])
         p = jnp.where(mask, jnp.exp(s - m_new[:, :1]), 0.0)
         l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
+        if vsc_ref is not None:  # sum_j p_j (vs_j v_j) == sum_j (p_j vs_j) v_j
+            p = p * vsc_ref[0, 0]
         acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
             p, v, preferred_element_type=jnp.float32
         )
@@ -731,9 +738,9 @@ def mha_chunk_paged(
     ``s_kv`` is the VIRTUAL cache length (fine masks index virtual token
     positions; the per-query pattern gate runs on virtual tiles).  Same grid
     semantics as :func:`mha_chunk` with the batch and kv-head axes split so
-    the pool needs no per-row copy.  ``k_scale`` / ``v_scale`` ((KV,
-    n_pages * kv_tile) float32, or None): quantized-pool per-row dequant
-    scales, page-indirected like K/V and applied right after the tile DMA.
+    the pool needs no per-row copy.  ``k_scale`` / ``v_scale`` ((n_pages,
+    KV, 1, kv_tile) float32, or None): quantized-pool per-row dequant
+    scales, page-indirected like K/V (layout as in :func:`mha_prefill`).
     Returns (B, KV, G, C_pad, D)."""
     from jax.experimental.pallas import tpu as pltpu
 
@@ -769,7 +776,8 @@ def mha_chunk_paged(
     ]
     if quantized:
         sspec = pl.BlockSpec(
-            (1, kv_tile), lambda b, h, gg, jj, st, kvi, vt, lv: (h, kvi[b, jj])
+            (1, 1, 1, kv_tile),
+            lambda b, h, gg, jj, st, kvi, vt, lv: (kvi[b, jj], h, 0, 0),
         )
         in_specs += [sspec, sspec]
         args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]

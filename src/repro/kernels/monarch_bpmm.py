@@ -34,21 +34,35 @@ from jax.experimental import pallas as pl
 __all__ = ["monarch_bpmm", "pick_token_tile"]
 
 
-def pick_token_tile(gin: int, nb: int, b: int, dtype_bytes: float = 4) -> int:
-    """Token-tile size so x/u/y tiles fit a ~12 MB VMEM budget.
+# Mosaic's default scoped-VMEM limit for one kernel on TPU v5e; a kernel whose
+# blocks and temporaries exceed it is refused at compile time
+_SCOPED_VMEM = 16 * 1024 * 1024
 
-    ``dtype_bytes`` must come from the ACTUAL storage dtype (bf16 tiles are
-    half the bytes of f32 and fit twice the tokens); the f32 default is a
-    conservative fallback for callers without an array in hand.  Fractional
-    widths are legal: quantized KV tiles price at their EFFECTIVE width —
-    e.g. ``repro.core.attention.kv_dtype_bytes`` returns ``1 + 4/head_dim``
-    for int8/fp8 pages (payload byte + amortized per-row f32 scale) — so a
-    quantized stream budgets nearly twice the tokens of bf16 in the same
-    VMEM."""
-    piece = nb * b
-    per_token = (gin + 3) * piece * float(dtype_bytes)  # x(gin) + u + acc + y
-    budget = 12 * 1024 * 1024
-    tile = int(budget // max(per_token, 1.0))
+
+def _padded(rows: int, lanes: int) -> int:
+    """Elements a (rows, lanes) trailing tile occupies in VMEM: TPU vregs
+    tile the last two dims (8, 128), so b=32 lanes cost 128."""
+    return -(-rows // 8) * 8 * (-(-lanes // 128) * 128)
+
+
+def pick_token_tile(gin: int, nb: int, b: int, dtype_bytes: float = 4) -> int:
+    """Largest token tile whose VMEM footprint fits the scoped limit.
+
+    The footprint is priced at the PADDED (8, 128) layout: per token, the
+    x block (gin slabs of (nb, b)) and the y block (one slab), each
+    double-buffered by the pipeline, plus one f32 slab per input slice for
+    the upcasts of the unrolled slice loop; fixed, the r and l blocks
+    (double-buffered) and one slice's f32 upcasts of them.
+
+    ``dtype_bytes`` must come from the ACTUAL storage dtype (bf16 blocks
+    are half the bytes of f32); the f32 default is the conservative choice
+    for callers without an array in hand.  Fractional widths are legal and
+    price a block at that effective width."""
+    slab = _padded(nb, b)
+    per_token = 2 * (gin + 1) * slab * float(dtype_bytes) + 4 * gin * slab
+    weights = 2 * gin * nb * _padded(b, b) * 2 * float(dtype_bytes)
+    weights += 2 * nb * _padded(b, b) * 4
+    tile = int((_SCOPED_VMEM - weights) // max(per_token, 1.0))
     for cand in (512, 256, 128, 64, 32, 16, 8):
         if cand <= tile:
             return cand

@@ -411,16 +411,22 @@ def _pool_layout(k_pool: jax.Array, v_pool: jax.Array, page: int):
     return kt, vt, rows // page, d
 
 
-def _scale_layout(k_scale, v_scale):
-    """(P*page, KV) per-row dequant scales -> kernel layout (KV, P*page) f32
-    — the scale analogue of :func:`_pool_layout` (no head_dim axis to pad;
-    the scale tile rides the page index map, so lanes are the page rows)."""
+def _scale_layout(k_scale, v_scale, page: int):
+    """(P*page, KV) per-row dequant scales -> kernel layout (P, KV, 1, page)
+    f32 — the scale analogue of :func:`_pool_layout`.  A page's scales for
+    one kv head are one lane row, and the kernels' (1, 1, 1, page) block then
+    has its last two dims equal to the array's, as the TPU tiling requires
+    (a (1, page) block over (KV, P*page) has a second-minor 1 that is
+    neither a multiple of 8 nor the full KV extent)."""
     if k_scale is None:
         return None, None
-    return (
-        jnp.swapaxes(k_scale, 0, 1).astype(jnp.float32),
-        jnp.swapaxes(v_scale, 0, 1).astype(jnp.float32),
-    )
+
+    def lay(s):
+        rows, kvh = s.shape
+        s = s.astype(jnp.float32).reshape(rows // page, page, kvh)
+        return jnp.swapaxes(s, 1, 2)[:, :, None, :]
+
+    return lay(k_scale), lay(v_scale)
 
 
 def _virtual_extent(page_table: jax.Array, page: int, kv_live: int | None) -> int:
@@ -496,7 +502,7 @@ def flash_paged_prefill(
     qt = q.reshape(1, s, kvh, g, hd).transpose(0, 2, 3, 1, 4).reshape(kvh, g, s, hd)
     qt = jnp.pad(qt, ((0, 0), (0, 0), (0, sq_pad - s), (0, d - hd)))
 
-    ks, vs = _scale_layout(k_scale, v_scale)
+    ks, vs = _scale_layout(k_scale, v_scale, page)
     y = fa.mha_prefill(
         qt, kt, vt, kv_phys, step_live,
         scale=1.0 / math.sqrt(hd), causal=causal, window=window,
@@ -581,7 +587,7 @@ def flash_paged_chunk(
     qt = q.reshape(b, c, kvh, g, hd).transpose(0, 2, 3, 1, 4)
     qt = jnp.pad(qt, ((0, 0), (0, 0), (0, 0), (0, cp - c), (0, d - hd)))
 
-    ks, vs = _scale_layout(k_scale, v_scale)
+    ks, vs = _scale_layout(k_scale, v_scale, page)
     y = fa.mha_chunk_paged(
         qt, kt, vt, start, kv_phys, kv_virt, step_live,
         scale=1.0 / math.sqrt(hd), window=window, s_kv=skv,
@@ -654,7 +660,7 @@ def flash_paged_decode(
 
     qt = jnp.pad(q.reshape(b, kvh, g, hd), ((0, 0), (0, 0), (0, gp - g), (0, d - hd)))
 
-    ks, vs = _scale_layout(k_scale, v_scale)
+    ks, vs = _scale_layout(k_scale, v_scale, page)
     y = fa.mha_decode_paged(
         qt, kt, vt, cl_rows, kv_phys, kv_virt, step_live,
         scale=1.0 / math.sqrt(hd), window=window, kv_tile=page,

@@ -10,13 +10,14 @@ __all__ = [
 ]
 
 
-def make_mesh(shape, names):
-    """jax.make_mesh across jax versions: `axis_types=Auto` where the kwarg
-    exists (>= 0.5), plain call where it doesn't (0.4.x defaults to auto)."""
-    at = getattr(jax.sharding, "AxisType", None)
-    if at is not None:
-        return jax.make_mesh(shape, names, axis_types=(at.Auto,) * len(names))
-    return jax.make_mesh(shape, names)
+def make_mesh(shape, names, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD propagates the
+    shardings the logical-axis rules leave open).  ``devices`` defaults to
+    the first ``prod(shape)`` of ``jax.devices()``."""
+    return jax.make_mesh(
+        shape, names, axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+        devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -27,7 +28,11 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_local_mesh():
-    """Whatever this host has (CPU smoke runs: 1 device)."""
+    """A data-parallel mesh over every device of this host (CPU tests: 1
+    device).  With more than one device the fused attention kernels are
+    refused (``models.layers._fused``): build a one-device mesh with
+    ``make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])`` or
+    ask for ``attn_impl="xla_chunked"``."""
     n = len(jax.devices())
     return make_mesh((n, 1), ("data", "model"))
 

@@ -52,6 +52,7 @@ from repro.launch.serving.queueing import (
     _PagedSlot,
     _PRIORITY_RANK,
     _next_bucket,
+    _to_device,
 )
 
 __all__ = ["PrefillWorker", "DecodeWorker", "DisaggRouter"]
@@ -340,7 +341,7 @@ class DisaggRouter(ServeLoop):
                     ).astype(np.int32)
                     logits, caches = self.p_decode_fn(
                         self.params, caches, dw.nxt[:, None],
-                        jnp.asarray(dw.pos), jnp.asarray(pt_wave), kv_live,
+                        _to_device(dw.pos), jnp.asarray(pt_wave), kv_live,
                     )
                     toks = jnp.argmax(logits, -1).astype(jnp.int32)
                     self.stats["decode_steps"] += 1
@@ -401,7 +402,7 @@ class DisaggRouter(ServeLoop):
                     )
                     logits1, caches = self.p_chunk_fn(
                         self.params, caches, jnp.asarray(ctoks),
-                        jnp.asarray(pw.pt[slot : slot + 1]),
+                        _to_device(pw.pt[slot : slot + 1]),
                         jnp.int32(pw.pos[slot]), jnp.int32(t), kv_live,
                     )
                     did_chunk = True

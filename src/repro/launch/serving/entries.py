@@ -322,6 +322,10 @@ def make_paged_fns(
       (:func:`repro.models.transformer.paged_encode`); the written pages
       are read-only for the rest of their life and alias freely.
 
+    ``decode.jit_for(kv_live)`` / ``chunk_fn.jit_for(kv_live)`` return the
+    jitted program of one bucket (built on first use), so a caller can
+    ``.lower(...)`` it — to compile ahead or to inspect the compiled module.
+
     All entry points donate the pools; the page tables are tiny replicated
     int32 arrays refreshed from host state every call.  On a mesh with a
     ``pages`` axis the pool's page rows are SHARDED over it — each device
@@ -359,8 +363,7 @@ def make_paged_fns(
 
     dec_jit: dict[int | None, object] = {}
 
-    def decode(params, caches, tokens, pos, pt, kv_live: int | None = None,
-               ct=None):
+    def decode_jit(kv_live: int | None = None):
         fn = dec_jit.get(kv_live)
         if fn is None:
             if cross_pages is not None:
@@ -385,18 +388,18 @@ def make_paged_fns(
                     donate_argnums=(1,),
                 )
             dec_jit[kv_live] = fn
+        return fn
+
+    def decode(params, caches, tokens, pos, pt, kv_live: int | None = None,
+               ct=None):
+        fn = decode_jit(kv_live)
         if cross_pages is not None:
             return fn(params, caches, tokens, pos, pt, ct)
         return fn(params, caches, tokens, pos, pt)
 
     chk_jit: dict[int | None, object] = {}
 
-    def chunk_fn(params, caches, tokens, pt, pos, ntok,
-                 kv_live: int | None = None, ct=None):
-        if tokens.shape != (1, chunk):
-            raise ValueError(
-                f"tokens {tokens.shape} vs compiled chunk shape {(1, chunk)}"
-            )
+    def chunk_jit(kv_live: int | None = None):
         fn = chk_jit.get(kv_live)
         if fn is None:
             def _step(params, caches, tokens, pt, pos, ntok, ct=None):
@@ -423,9 +426,22 @@ def make_paged_fns(
                     donate_argnums=(1,),
                 )
             chk_jit[kv_live] = fn
+        return fn
+
+    def chunk_fn(params, caches, tokens, pt, pos, ntok,
+                 kv_live: int | None = None, ct=None):
+        if tokens.shape != (1, chunk):
+            raise ValueError(
+                f"tokens {tokens.shape} vs compiled chunk shape {(1, chunk)}"
+            )
+        fn = chunk_jit(kv_live)
         if cross_pages is not None:
             return fn(params, caches, tokens, pt, pos, ntok, ct)
         return fn(params, caches, tokens, pt, pos, ntok)
+
+    # the jitted program of each kv_live bucket, for lowering and inspection
+    decode.jit_for = decode_jit
+    chunk_fn.jit_for = chunk_jit
 
     copy_fn = jax.jit(
         lambda caches, src, dst: tf.paged_copy_page(caches, src, dst, page),

@@ -43,6 +43,7 @@ from repro.launch.serving.queueing import (
     _PagedSlot,
     _PRIORITY_RANK,
     _next_bucket,
+    _to_device,
 )
 
 __all__ = ["ServeLoop"]
@@ -632,7 +633,7 @@ class ServeLoop:
             kv_live = _next_bucket(p + t, self.cache_len)
             logits1, caches = self.p_chunk_fn(
                 self.params, caches, jnp.asarray(ctoks),
-                jnp.asarray(pt[slot : slot + 1]), jnp.int32(p), jnp.int32(t),
+                _to_device(pt[slot : slot + 1]), jnp.int32(p), jnp.int32(t),
                 kv_live, ct=ct,
             )
             self.stats["chunk_calls"] = self.stats.get("chunk_calls", 0) + 1
@@ -681,7 +682,7 @@ class ServeLoop:
         ct[slot, :n] = pages
         caches = self.p_encode_fn(
             self.params, caches, jnp.asarray(frames)[None],
-            jnp.asarray(ct[slot : slot + 1]),
+            _to_device(ct[slot : slot + 1]),
         )
         for p in pages:  # the request's own reference; alloc's is the cache's
             self.cross_pool.retain(p, owner=f"req{r.uid}")
@@ -974,7 +975,7 @@ class ServeLoop:
                         self.stats.get("decode_kv_live_max", 0), kv_live
                     )
                 logits, caches = self.decode_fn(
-                    self.params, caches, nxt[:, None], jnp.asarray(pos), kv_live,
+                    self.params, caches, nxt[:, None], _to_device(pos), kv_live,
                 )
                 self.stats["decode_steps"] += 1
                 clock += 1
@@ -1096,7 +1097,7 @@ class ServeLoop:
                     )
                     logits, caches = self.mixed1_fn(
                         self.params, caches, zeros_b1, nxt,
-                        jnp.asarray(use_nxt), jnp.asarray(pos),
+                        jnp.asarray(use_nxt), _to_device(pos),
                         jnp.asarray(ntok_a), kv_live,
                     )
                     toks = jnp.argmax(logits, -1).astype(jnp.int32)
@@ -1281,7 +1282,7 @@ class ServeLoop:
                     if any(a is not None for a in active):
                         self.stats["admission_stall_steps"] += 1
                     ct_row = (
-                        None if ct is None else jnp.asarray(ct[slot:slot + 1])
+                        None if ct is None else _to_device(ct[slot:slot + 1])
                     )
                     if m:
                         for i, p in enumerate(spages):
@@ -1316,7 +1317,7 @@ class ServeLoop:
                         logits, caches = self.p_prefill_fn(
                             self.params, caches, {"tokens": jnp.asarray(toks)},
                             jnp.asarray([plen], jnp.int32),
-                            jnp.asarray(pt[slot : slot + 1]),
+                            _to_device(pt[slot : slot + 1]),
                         )
                         self.stats["prefill_calls"] += 1
                         self.stats["prefill_tokens"] += plen
@@ -1371,9 +1372,9 @@ class ServeLoop:
                         self.stats.get("decode_kv_live_max", 0), kv_live
                     )
                 logits, caches = self.p_decode_fn(
-                    self.params, caches, nxt[:, None], jnp.asarray(pos),
-                    jnp.asarray(pt), kv_live,
-                    **({} if ct is None else {"ct": jnp.asarray(ct)}),
+                    self.params, caches, nxt[:, None], _to_device(pos),
+                    _to_device(pt), kv_live,
+                    **({} if ct is None else {"ct": _to_device(ct)}),
                 )
                 self.stats["decode_steps"] += 1
                 clock += 1
@@ -1623,9 +1624,9 @@ class ServeLoop:
                         use[:, None], pt, np.int32(self.pool_pages)
                     ).astype(np.int32)
                     logits, caches = self.p_decode_fn(
-                        self.params, caches, nxt[:, None], jnp.asarray(pos),
+                        self.params, caches, nxt[:, None], _to_device(pos),
                         jnp.asarray(pt_wave), kv_live,
-                        **({} if ct is None else {"ct": jnp.asarray(ct)}),
+                        **({} if ct is None else {"ct": _to_device(ct)}),
                     )
                     toks = jnp.argmax(logits, -1).astype(jnp.int32)
                     self.stats["decode_steps"] += 1
@@ -1668,11 +1669,9 @@ class ServeLoop:
                     kv_live = _next_bucket(int(pos[slot]) + t, self.cache_len)
                     logits1, caches = self.p_chunk_fn(
                         self.params, caches, jnp.asarray(ctoks),
-                        jnp.asarray(pt[slot : slot + 1]),
+                        _to_device(pt[slot : slot + 1]),
                         jnp.int32(pos[slot]), jnp.int32(t), kv_live,
-                        ct=None if ct is None else jnp.asarray(
-                            ct[slot : slot + 1]
-                        ),
+                        ct=None if ct is None else _to_device(ct[slot : slot + 1]),
                     )
                     self.stats["chunk_calls"] += 1
                     self.stats["prefill_tokens"] += t

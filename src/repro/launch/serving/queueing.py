@@ -7,6 +7,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 
+import jax.numpy as jnp
 import numpy as np
 
 __all__ = [
@@ -16,6 +17,7 @@ __all__ = [
     "_AdmitQueue",
     "_AsyncTokens",
     "_next_bucket",
+    "_to_device",
 ]
 
 
@@ -129,6 +131,17 @@ def _next_bucket(n: int, cap: int, floor: int = 8) -> int:
     return min(b, cap)
 
 
+def _to_device(host: np.ndarray):
+    """A device copy of host scheduler state (positions, page and cross
+    tables) that the engine mutates in place after dispatching a step.
+    The copy is made on the host: the CPU backend aliases a 64-byte-aligned
+    numpy buffer instead of copying it (``jnp.array(copy=True)`` included —
+    its copy runs on the device, queued behind earlier steps), so a later
+    ``pos[slot] += 1`` or a freed page-table entry would change the input of
+    a step that is still queued."""
+    return jnp.asarray(np.array(host))
+
+
 class _AsyncTokens:
     """One-step-lag device-to-host token fetch.
 
@@ -144,10 +157,7 @@ class _AsyncTokens:
         self._q: collections.deque = collections.deque()
 
     def push(self, dev, sinks: list[tuple[Request, int]]) -> None:
-        try:
-            dev.copy_to_host_async()
-        except AttributeError:  # non-array backends / older jax
-            pass
+        dev.copy_to_host_async()
         self._q.append((dev, sinks))
         while len(self._q) > self.lag:
             self._resolve()

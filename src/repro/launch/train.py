@@ -184,7 +184,7 @@ def make_train_step(
                 g_sync, new_err = psum_compressed(g, err, "pod")
                 return g_sync, new_err, l, metrics
 
-            grads, new_err, l, metrics = shd.shard_map(
+            grads, new_err, l, metrics = jax.shard_map(
                 pod_grads,
                 mesh=mesh,
                 in_specs=(P(), P(), P("pod")),

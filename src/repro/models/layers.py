@@ -58,7 +58,6 @@ class Runtime:
     mesh: Mesh | None = None
     attn_mode: str = "tp"  # tp (head-sharded) | cp (sequence-sharded)
     moe_mode: str = "ep"  # ep | tp
-    interpret: bool = True  # Pallas kernels in interpret mode (CPU host)
     rules: dict | None = None  # sharding-rule override (pure_dp lever)
 
 
@@ -285,11 +284,21 @@ def chunk_attention_cache(
     return out.reshape(b, c, h, hd)
 
 
-def _fused_ok(rt: Runtime) -> bool:
-    # pallas_call is a per-device kernel: under a >1-chip mesh the SPMD
-    # partitioner cannot split it, so the spec falls back to the XLA form
-    # (which the partitioner shards freely) instead of erroring.
-    return rt.mesh is None or rt.mesh.devices.size <= 1
+def _fused(spec: AttentionSpec, rt: Runtime) -> bool:
+    """True when ``spec`` selects the fused kernel.  A ``pallas_call`` is a
+    per-device kernel the SPMD partitioner cannot split, so a
+    ``flash_kernel`` spec on a mesh of more than one device is refused — it
+    is never swapped for the XLA form, which would read every dead tile."""
+    if not spec.fused:
+        return False
+    if rt.mesh is not None and rt.mesh.devices.size > 1:
+        raise ValueError(
+            "attention impl 'flash_kernel' is one Pallas kernel per device "
+            "and cannot be partitioned over the "
+            f"{rt.mesh.devices.size}-device mesh {dict(rt.mesh.shape)}; ask "
+            "for impl='xla_chunked' on a multi-device mesh"
+        )
+    return True
 
 
 def run_attention(
@@ -306,9 +315,9 @@ def run_attention(
 
     ``spec.pattern`` applies to both forms: the fused kernel iterates only
     live blocks (grid-level skipping); the chunked form masks with the same
-    map's token expansion (mask-only — parity target and multi-chip
-    fallback)."""
-    if spec.fused and _fused_ok(rt):
+    map's token expansion (mask-only — the parity target, and the form a
+    multi-device mesh must ask for)."""
+    if _fused(spec, rt):
         from repro.kernels import ops  # local import: kernels are optional
 
         return ops.flash_attention(q, k, v, causal=causal, window=window, spec=spec)
@@ -349,7 +358,7 @@ def run_decode_attention(
     engine's bucketed ``max(pos)+1``): both forms read only the first
     ``kv_live`` cache rows instead of streaming the padded cache.
     ``spec.pattern`` restricts each row to its own live kv tiles."""
-    if spec.fused and _fused_ok(rt):
+    if _fused(spec, rt):
         from repro.kernels import ops
 
         return ops.flash_decode(
@@ -481,7 +490,7 @@ def run_paged_prefill_attention(
     the in-flight projections directly (the gather would reproduce them, and
     for a QUANTIZED pool the in-flight values are the exact pre-quantization
     KV — no dequant needed)."""
-    if spec.fused and _fused_ok(rt):
+    if _fused(spec, rt):
         from repro.kernels import ops
 
         return ops.flash_paged_prefill(
@@ -514,9 +523,9 @@ def run_paged_decode_attention(
     positions are unbounded, the table's ``ring_tiles`` slots are reused in
     phase, and only the trailing ``ring_window`` keys are live.
     ``k_scale`` / ``v_scale`` carry a quantized pool's per-row dequant
-    scales: the fused kernel dequantizes post-DMA, the XLA forms right after
-    the gather — one scheme, two address spaces."""
-    if spec.fused and _fused_ok(rt):
+    scales: the fused kernel applies them to the score tile, the XLA forms
+    right after the gather — one scheme, two address spaces."""
+    if _fused(spec, rt):
         from repro.kernels import ops
 
         return ops.flash_paged_decode(
@@ -567,8 +576,8 @@ def run_paged_chunk_attention(
     to physical pages.  ``ring_window`` / ``ring_tiles`` select the
     mod-window ring form (slot-phase tables, absolute-position masks).
     ``k_scale`` / ``v_scale``: quantized-pool dequant scales (fused:
-    post-DMA in-kernel; XLA: post-gather)."""
-    if spec.fused and _fused_ok(rt):
+    on the score tile in-kernel; XLA: post-gather)."""
+    if _fused(spec, rt):
         from repro.kernels import ops
 
         return ops.flash_paged_chunk(
@@ -617,7 +626,7 @@ def run_chunk_attention(
     ``start + ntok``); the XLA form masks with the same map's per-query token
     expansion.  ``kv_live`` is the engine's bucketed static bound on the
     hottest row's frontier — both forms read only that cache prefix."""
-    if spec.fused and _fused_ok(rt):
+    if _fused(spec, rt):
         from repro.kernels import ops
 
         return ops.flash_chunk(

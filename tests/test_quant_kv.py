@@ -40,9 +40,7 @@ def _f32(cfg):
     return dataclasses.replace(cfg, dtype="float32", capacity_factor=8.0)
 
 
-STORE_DTYPES = [("int8", jnp.int8)] + (
-    [("fp8_e4m3", jnp.float8_e4m3fn)] if quant.fp8_supported() else []
-)
+STORE_DTYPES = [("int8", jnp.int8), ("fp8_e4m3", jnp.float8_e4m3fn)]
 
 
 # --------------------------------------------------------------------------
@@ -79,11 +77,10 @@ def test_kv_dtype_validation_and_store():
         quant.validate_kv_dtype("int4")
     assert quant.kv_store_dtype("bf16", jnp.float32) == jnp.dtype(jnp.float32)
     assert quant.kv_store_dtype("int8", jnp.float32) == jnp.dtype(jnp.int8)
-    if quant.fp8_supported():
-        assert (
-            quant.kv_store_dtype("fp8_e4m3", jnp.float32)
-            == jnp.dtype(jnp.float8_e4m3fn)
-        )
+    assert (
+        quant.kv_store_dtype("fp8_e4m3", jnp.float32)
+        == jnp.dtype(jnp.float8_e4m3fn)
+    )
     # quantized widths price payload + amortized f32 scale per head_dim values
     assert kv_dtype_bytes("bf16", 64) == 2.0
     assert kv_dtype_bytes("int8", 64) == pytest.approx(1.0 + 4.0 / 64)
@@ -101,7 +98,7 @@ def test_pick_token_tile_quantized_width():
     """At a geometry pinched between tile candidates, the quantized effective
     width (1 + 4/hd bytes) must admit a strictly larger token tile than bf16
     — the VMEM budget prices true bytes, not container dtypes."""
-    gin, nb, b = 125, 8, 16  # (gin+3) * nb * b = 16384 bytes/token at 1B
+    gin, nb, b = 2, 16, 32  # padded slab 16 x 128; 256 < tile(bf16) < 512
     t_bf16 = pick_token_tile(gin, nb, b, dtype_bytes=2.0)
     t_int8 = pick_token_tile(gin, nb, b, dtype_bytes=kv_dtype_bytes("int8", 64))
     assert t_int8 > t_bf16
@@ -203,7 +200,7 @@ QUANT_CASES = [
         ("dense", None, 128), ("window", 16, 128), ("butterfly", None, 512),
     )
     for impl in ("xla_chunked", "flash_kernel")
-    for kd in ("bf16", "int8") + (("fp8_e4m3",) if quant.fp8_supported() else ())
+    for kd in ("bf16", "int8", "fp8_e4m3")
 ]
 
 

@@ -283,6 +283,29 @@ def test_chunked_engine_matches_admission_engine(
             ), f"uid {r.uid} vs isolated"
 
 
+def test_host_state_reaches_device_as_snapshot():
+    """Scheduler state goes to the device as a snapshot: the engine's
+    in-place update right after a dispatch (``pos[slot] += 1``) must not
+    reach a step still queued behind earlier work.  The CPU backend aliases
+    64-byte-aligned numpy buffers, so without the host copy greedy streams
+    changed from run to run."""
+    import numpy as np
+
+    from repro.launch.serving.queueing import _to_device
+
+    busy = jax.jit(lambda a: (a @ a) @ a)
+    step = jax.jit(lambda a, x: x + 0 * a[0, 0].astype(jnp.int32))
+    a = jnp.ones((512, 512))
+    buf = np.zeros(8 + 16, np.int32)
+    off = (-buf.ctypes.data % 64) // 4
+    pos = buf[off : off + 8]  # 64-byte aligned, as the allocator may hand out
+    for _ in range(8):
+        pos[:] = 0
+        out = step(busy(a), _to_device(pos))
+        pos += 1
+        assert np.asarray(out).tolist() == [0] * 8
+
+
 def test_chunked_decode_never_stalls_on_admission():
     """A long prompt arriving mid-decode must stream in chunks WHILE the live
     decode rows keep sampling: zero decode stalls, overlap steps observed,

@@ -134,7 +134,6 @@ def test_wire_compression_shard_map():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.distributed.sharding import shard_map
         from repro.launch.mesh import make_mesh
         from repro.optim.compression import psum_compressed
         mesh = make_mesh((4, 2), ("pod", "data"))
@@ -143,7 +142,7 @@ def test_wire_compression_shard_map():
         def f(g, e):
             mean, new_e = psum_compressed({"g": g[0]}, {"g": e[0]}, "pod")
             return mean["g"], new_e["g"][None]
-        fn = shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
+        fn = jax.shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
                        out_specs=(P(), P("pod")), axis_names={"pod"})
         with mesh:
             mean, new_err = fn(g, err)
@@ -168,3 +167,38 @@ def test_param_shardings_cover_all_leaves():
         n_specs = len(jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, ParamSpec)))
         n_sh = len(jax.tree.leaves(sh))
         assert n_specs == n_sh > 0
+
+
+def test_flash_kernel_refused_on_multidevice_mesh():
+    """A flash_kernel spec on a mesh of more than one device raises and names
+    the form to ask for, instead of quietly running the XLA form."""
+    out = _run_subprocess("""
+        import jax.numpy as jnp
+        from repro.core.attention import AttentionSpec
+        from repro.launch.mesh import make_mesh
+        from repro.models.layers import Runtime, run_attention
+        rt = Runtime(mesh=make_mesh((2, 1), ("data", "model")))
+        q = jnp.ones((2, 8, 2, 16))
+        try:
+            run_attention(q, q, q, spec=AttentionSpec(impl="flash_kernel"), rt=rt)
+        except ValueError as e:
+            assert "xla_chunked" in str(e), e
+            print("REFUSED")
+        y = run_attention(q, q, q, spec=AttentionSpec(impl="xla_chunked"), rt=rt)
+        assert y.shape == q.shape
+    """, ndev=2)
+    assert "REFUSED" in out
+
+
+def test_chip_smoke_refuses_cpu():
+    """chip_smoke.py exits nonzero on a host without a TPU, names the
+    platform it found, and prints no result line."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    script = os.path.join(os.path.dirname(__file__), "..", "chip_smoke.py")
+    out = subprocess.run(
+        [sys.executable, script], capture_output=True, text=True, env=env,
+        timeout=300,
+    )
+    assert out.returncode != 0
+    assert "'cpu'" in out.stderr, out.stderr[-2000:]
+    assert '"ok"' not in out.stdout
