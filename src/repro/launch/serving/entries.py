@@ -351,11 +351,16 @@ def make_paged_fns(
     )
     rep = NamedSharding(mesh, P())
 
-    prefill = jax.jit(
-        lambda params, caches, b, lengths, pt: tf.paged_prefill(
+    # each program is a named function, so its jitted module is named after
+    # it (``jit_paged_decode``) in compile logs and profiler traces
+    def paged_prefill(params, caches, b, lengths, pt):
+        return tf.paged_prefill(
             params, cfg, b, rt, caches=caches, page_table=pt, page=page,
             lengths=lengths,
-        ),
+        )
+
+    prefill = jax.jit(
+        paged_prefill,
         in_shardings=(p_shard, pool_shard, None, rep, rep),
         out_shardings=(tok_shard, pool_shard),
         donate_argnums=(1,),
@@ -366,27 +371,20 @@ def make_paged_fns(
     def decode_jit(kv_live: int | None = None):
         fn = dec_jit.get(kv_live)
         if fn is None:
-            if cross_pages is not None:
-                fn = jax.jit(
-                    lambda params, caches, tokens, pos, pt, ct: tf.decode_step(
-                        params, cfg, caches, tokens, pos, rt, kv_live=kv_live,
-                        page_table=pt, page=page, cross_table=ct,
-                    ),
-                    in_shardings=(p_shard, pool_shard, tok_shard, rep, rep,
-                                  rep),
-                    out_shardings=(tok_shard, pool_shard),
-                    donate_argnums=(1,),
+            def paged_decode(params, caches, tokens, pos, pt, ct=None):
+                return tf.decode_step(
+                    params, cfg, caches, tokens, pos, rt, kv_live=kv_live,
+                    page_table=pt, page=page, cross_table=ct,
                 )
-            else:
-                fn = jax.jit(
-                    lambda params, caches, tokens, pos, pt: tf.decode_step(
-                        params, cfg, caches, tokens, pos, rt, kv_live=kv_live,
-                        page_table=pt, page=page,
-                    ),
-                    in_shardings=(p_shard, pool_shard, tok_shard, rep, rep),
-                    out_shardings=(tok_shard, pool_shard),
-                    donate_argnums=(1,),
-                )
+
+            cross = (rep,) if cross_pages is not None else ()
+            fn = jax.jit(
+                paged_decode,
+                in_shardings=(p_shard, pool_shard, tok_shard, rep, rep)
+                + cross,
+                out_shardings=(tok_shard, pool_shard),
+                donate_argnums=(1,),
+            )
             dec_jit[kv_live] = fn
         return fn
 
@@ -402,7 +400,7 @@ def make_paged_fns(
     def chunk_jit(kv_live: int | None = None):
         fn = chk_jit.get(kv_live)
         if fn is None:
-            def _step(params, caches, tokens, pt, pos, ntok, ct=None):
+            def paged_chunk(params, caches, tokens, pt, pos, ntok, ct=None):
                 logits, caches = tf.mixed_step(
                     params, cfg, caches, tokens, jnp.reshape(pos, (1,)),
                     jnp.reshape(ntok, (1,)), rt, kv_live=kv_live,
@@ -410,21 +408,13 @@ def make_paged_fns(
                 )
                 return logits[0], caches
 
-            if cross_pages is not None:
-                fn = jax.jit(
-                    _step,
-                    in_shardings=(p_shard, pool_shard, rep, rep, rep, rep,
-                                  rep),
-                    out_shardings=(rep, pool_shard),
-                    donate_argnums=(1,),
-                )
-            else:
-                fn = jax.jit(
-                    _step,
-                    in_shardings=(p_shard, pool_shard, rep, rep, rep, rep),
-                    out_shardings=(rep, pool_shard),
-                    donate_argnums=(1,),
-                )
+            cross = (rep,) if cross_pages is not None else ()
+            fn = jax.jit(
+                paged_chunk,
+                in_shardings=(p_shard, pool_shard, rep, rep, rep, rep) + cross,
+                out_shardings=(rep, pool_shard),
+                donate_argnums=(1,),
+            )
             chk_jit[kv_live] = fn
         return fn
 
@@ -443,8 +433,11 @@ def make_paged_fns(
     decode.jit_for = decode_jit
     chunk_fn.jit_for = chunk_jit
 
+    def paged_copy_page(caches, src, dst):
+        return tf.paged_copy_page(caches, src, dst, page)
+
     copy_fn = jax.jit(
-        lambda caches, src, dst: tf.paged_copy_page(caches, src, dst, page),
+        paged_copy_page,
         in_shardings=(pool_shard, rep, rep),
         out_shardings=pool_shard,
         donate_argnums=(0,),
@@ -452,11 +445,14 @@ def make_paged_fns(
 
     encode_fn = None
     if cross_pages is not None:
-        encode_fn = jax.jit(
-            lambda params, caches, frames, ct: tf.paged_encode(
+        def paged_encode(params, caches, frames, ct):
+            return tf.paged_encode(
                 params, cfg, frames, rt, caches=caches, cross_table=ct,
                 page=page,
-            ),
+            )
+
+        encode_fn = jax.jit(
+            paged_encode,
             in_shardings=(p_shard, pool_shard, None, rep),
             out_shardings=pool_shard,
             donate_argnums=(1,),

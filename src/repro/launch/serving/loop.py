@@ -13,6 +13,7 @@ and reuses its schedule/reservation/preemption machinery."""
 from __future__ import annotations
 
 import collections
+import time
 
 import jax
 import jax.numpy as jnp
@@ -47,6 +48,49 @@ from repro.launch.serving.queueing import (
 )
 
 __all__ = ["ServeLoop"]
+
+# host phases of the paged chunked engine: profiler span, self-time counter
+# and longest-self-time counter (none for resolve, where the host waits on
+# the device rather than working)
+_PHASES = {
+    name: (f"serve.{name}", f"host_{name}_s",
+           None if name == "resolve" else f"host_{name}_max_s")
+    for name in ("step", "admit", "decode", "chunk", "resolve")
+}
+
+
+class _Phase:
+    """One use of :meth:`ServeLoop._phase`: a profiler span around the
+    phase, and its host self time (its length less its child phases') added
+    to the loop's counters."""
+
+    __slots__ = ("loop", "keys", "ann", "t0")
+
+    def __init__(self, loop: "ServeLoop", name: str, step: int | None, kw):
+        self.loop, self.keys = loop, _PHASES[name]
+        span = self.keys[0]
+        self.ann = (
+            jax.profiler.StepTraceAnnotation(span, step_num=step, **kw)
+            if step is not None else jax.profiler.TraceAnnotation(span, **kw)
+        )
+
+    def __enter__(self) -> None:
+        self.loop._phase_open.append(0.0)
+        self.ann.__enter__()
+        self.t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> bool:
+        dt = time.perf_counter() - self.t0
+        self.ann.__exit__(*exc)
+        open_ = self.loop._phase_open
+        own = dt - open_.pop()
+        if open_:
+            open_[-1] += dt  # the parent's child time
+        stats, (_, total, longest) = self.loop.stats, self.keys
+        stats[total] += own
+        if longest is not None and own > stats[longest]:
+            stats[longest] = own
+        return False
 
 
 class ServeLoop:
@@ -207,6 +251,7 @@ class ServeLoop:
         self.slo_ttft = slo_ttft
         self.slo_itl = slo_itl
         self._closed = False
+        self._phase_open: list[float] = []  # child time of each open phase
         # preemption needs a page substrate to evict from and a restartable
         # resume path; rings hold fixed in-phase page sets and encdec KV
         # depends on the frames through cross-attention — both families are
@@ -415,7 +460,17 @@ class ServeLoop:
             r.generated.clear()
             r.emit_clocks.clear()
             r.ttft = None
+            r.admitted = None
             r.preemptions = 0
+
+    def _phase(self, name: str, step: int | None = None, **kw) -> _Phase:
+        """Context manager for one host phase of the paged chunked engine
+        (a name of ``_PHASES``): a ``serve.<name>`` profiler span carrying
+        ``kw`` (a step span numbered ``step`` when given), and the phase's
+        self time in ``stats["host_<name>_s"]`` and, but for resolve, its
+        longest single self time in ``stats["host_<name>_max_s"]``.  Always
+        on: with the profiler off a phase costs a few microseconds."""
+        return _Phase(self, name, step, kw)
 
     # -- engine loops -----------------------------------------------------
 
@@ -1438,7 +1493,7 @@ class ServeLoop:
         ct = None
         if self.cross_pages is not None:
             ct = np.full((B, self.cross_tiles), self.cross_pages, np.int32)
-        fetch = _AsyncTokens(lag=1)
+        fetch = _AsyncTokens(lag=1, phase=self._phase)
         self.stats = {
             "prefill_calls": 0, "mixed_steps": 0, "chunk_calls": 0,
             "decode_steps": 0, "prefill_tokens": 0, "decode_tokens": 0,
@@ -1447,6 +1502,10 @@ class ServeLoop:
             "prefill_flops": 0.0, "prefix_hits": 0, "prefix_hit_tokens": 0,
             "preemptions": 0, "resumes": 0, "resume_warm_hits": 0,
         }
+        for _, total, longest in _PHASES.values():
+            self.stats[total] = 0.0
+            if longest is not None:
+                self.stats[longest] = 0.0
         clock = 0
         rr = 0
         with self.mesh:
@@ -1454,249 +1513,272 @@ class ServeLoop:
                 self._pools if self._pools is not None else self._zero_pools()
             )
             while len(q) or any(r is not None for r in active):
-                # admission: a free slot AND a page reservation — the page
-                # budget, not the slot count, is the capacity limit; a
-                # higher-priority request that cannot reserve may evict the
-                # youngest lowest-priority active request instead of waiting
-                for slot in range(B):
-                    if active[slot] is not None:
-                        continue
-                    r = q.peek(clock)
-                    if r is None:
-                        break  # nothing in the queue has arrived yet
-                    pr = self._eff_prompt(r)  # prompt + resumed tokens
-                    L = len(pr) + (r.max_new - len(r.generated)) - 1
-                    own = f"req{r.uid}"
-                    rank = _PRIORITY_RANK[r.priority]
-                    m, spages = self._match_prefix(pr)
-                    if m:
-                        for p in spages:
-                            pool.retain(p, owner=own)
-                        sc = self._paged_schedule(
-                            L, step_span=C, start_tile=m // self.page
-                        )
-                        need = lambda: (
-                            self._committed(active, sched, pos)
-                            + sc.remaining_peak(m)
-                        )
-                        gap = self._fits(need())
-                        if gap > 0 and self.preemptible:
-                            gap = self._preempt_until(
-                                need, rank, q, fetch, pool, pt, active,
-                                sched, parr, pos, admit_pos, admit_seq,
-                            )
-                        if gap > 0:
-                            for p in spages:
-                                pool.release(p, owner=own)
-                            cold_peak = self._paged_schedule(
-                                L, step_span=C
-                            ).remaining_peak(0)
-                            if cold_peak < sc.remaining_peak(m):
-                                # cold genuinely cheaper (retention frees
-                                # tiles the alias would pin): retry cold
-                                m, spages = 0, []
-                            else:
-                                # cold could not fit either — and its _fits
-                                # would evict the very prefix (a preemption
-                                # victim's donated pages) that makes the
-                                # eventual resume warm
-                                self.stats["admission_backpressure"] += 1
-                                break
-                    if not m:
-                        sc = (
-                            self._ring_schedule(L)
-                            if self.ring_tiles is not None
-                            else self._paged_schedule(L, step_span=C)
-                        )
-                        need = lambda: (
-                            self._committed(active, sched, pos)
-                            + sc.remaining_peak(0)
-                        )
-                        gap = self._fits(need())
-                        if gap > 0 and self.preemptible:
-                            gap = self._preempt_until(
-                                need, rank, q, fetch, pool, pt, active,
-                                sched, parr, pos, admit_pos, admit_seq,
-                            )
-                        if gap > 0:
-                            self.stats["admission_backpressure"] += 1
-                            break
-                    if self.cross_pages is not None:
-                        nc = self._cross_admit(r, slot, ct, caches)
-                        if nc is None:
-                            self.stats["admission_backpressure"] += 1
-                            break
-                        caches = nc
-                    q.pop(r, clock)
-                    if r.preemptions:  # a victim re-admitting (possibly
-                        self.stats["resumes"] += 1  # mid-prefill, no tokens)
-                        if m:
-                            self.stats["resume_warm_hits"] += 1
-                    if m:
-                        for i, p in enumerate(spages):
-                            pt[slot, i] = p
-                        self.stats["prefix_hits"] += 1
-                        self.stats["prefix_hit_tokens"] += m
-                    elif self.ring_tiles is not None:
-                        # the fixed mod-window page set, allocated up front —
-                        # chunk streaming reuses the slots in phase
-                        for t in range(min(self.ring_tiles, -(-L // self.page))):
-                            pt[slot, t] = pool.alloc(own)
-                    active[slot] = r
-                    sched[slot] = sc
-                    parr[slot] = pr
-                    pos[slot] = m
-                    consumed[slot] = m
-                    admit_pos[slot] = m
-                    admit_seq[slot] = aseq
-                    aseq += 1
-                    remaining[slot] = r.max_new - len(r.generated)
-                self.stats["max_concurrent"] = max(
-                    self.stats["max_concurrent"],
-                    sum(a is not None for a in active),
-                )
-                if not any(r is not None for r in active):
-                    clock += 1
-                    continue
-                eligible = [
-                    s for s in range(B)
-                    if active[s] is not None
-                    and len(parr[s]) - consumed[s] <= 0
-                ]
-                use_nxt = np.zeros(B, bool)
-                chunk_t = np.zeros(B, np.int32)
-                budget = self.chunk_budget
-                # interactive rows split the chunk budget ahead of batch
-                # rows; the rotation keeps it fair within a class (and IS
-                # the whole order under uniform priority / fifo scheduling)
-                order = sorted(
-                    range(B),
-                    key=lambda s: (
-                        0 if self.fifo or active[s] is None
-                        else _PRIORITY_RANK[active[s].priority],
-                        (s - rr) % B,
-                    ),
-                )
-                for slot in order:
-                    r = active[slot]
-                    if r is None:
-                        continue
-                    rem_prompt = len(parr[slot]) - consumed[slot]
-                    if rem_prompt > 0:
-                        t = self._budget_draw(r, rem_prompt, budget)
-                        if t <= 0:
-                            continue
-                        chunk_t[slot] = t
-                        budget -= t
-                    else:
-                        use_nxt[slot] = True
-                rr = (rr + 1) % B
-                clock += 1
-                self.stats["mixed_steps"] += 1
-                dec_rows = [s for s in range(B) if use_nxt[s]]
-                chunk_rows = [s for s in range(B) if chunk_t[s] > 0]
-                if any(s not in dec_rows for s in eligible):
-                    self.stats["decode_stall_steps"] += 1
-                if dec_rows and chunk_rows:
-                    self.stats["overlap_steps"] += 1
-                # (a) paged decode wave: every decoding row advances through
-                # the decode grid; non-decoding rows run with a sentinel
-                # page-table row so their garbage write DROPS — a mid-prompt
-                # row's frontier tile may alias a shared prefix page, which
-                # an unmasked write would corrupt for every sibling
-                if dec_rows:
-                    for slot in dec_rows:
-                        caches = self._ensure_writable(
-                            pool, pt, slot, int(pos[slot]),
-                            int(pos[slot]) + 1, caches,
-                            f"req{active[slot].uid}",
-                        )
-                    if self.ring_tiles is not None:
-                        kv_live = None  # ring positions are unbounded
-                    else:
-                        hot = max(int(pos[s]) + 1 for s in dec_rows)
-                        kv_live = _next_bucket(hot, self.cache_len)
-                        self.stats["decode_kv_live_max"] = max(
-                            self.stats.get("decode_kv_live_max", 0), kv_live
-                        )
-                    use = np.asarray(use_nxt)
-                    pt_wave = np.where(
-                        use[:, None], pt, np.int32(self.pool_pages)
-                    ).astype(np.int32)
-                    logits, caches = self.p_decode_fn(
-                        self.params, caches, nxt[:, None], _to_device(pos),
-                        jnp.asarray(pt_wave), kv_live,
-                        **({} if ct is None else {"ct": _to_device(ct)}),
+                with self._phase("step", step=clock):
+                    # admission: a free slot AND a page reservation — the
+                    # page budget, not the slot count, is the capacity limit;
+                    # a higher-priority request that cannot reserve may evict
+                    # the youngest lowest-priority active request instead of
+                    # waiting
+                    with self._phase("admit"):
+                        for slot in range(B):
+                            if active[slot] is not None:
+                                continue
+                            r = q.peek(clock)
+                            if r is None:
+                                break  # nothing in the queue has arrived yet
+                            pr = self._eff_prompt(r)  # prompt + resumed tokens
+                            L = len(pr) + (r.max_new - len(r.generated)) - 1
+                            own = f"req{r.uid}"
+                            rank = _PRIORITY_RANK[r.priority]
+                            m, spages = self._match_prefix(pr)
+                            if m:
+                                for p in spages:
+                                    pool.retain(p, owner=own)
+                                sc = self._paged_schedule(
+                                    L, step_span=C, start_tile=m // self.page
+                                )
+                                need = lambda: (
+                                    self._committed(active, sched, pos)
+                                    + sc.remaining_peak(m)
+                                )
+                                gap = self._fits(need())
+                                if gap > 0 and self.preemptible:
+                                    gap = self._preempt_until(
+                                        need, rank, q, fetch, pool, pt, active,
+                                        sched, parr, pos, admit_pos, admit_seq,
+                                    )
+                                if gap > 0:
+                                    for p in spages:
+                                        pool.release(p, owner=own)
+                                    cold_peak = self._paged_schedule(
+                                        L, step_span=C
+                                    ).remaining_peak(0)
+                                    if cold_peak < sc.remaining_peak(m):
+                                        # cold genuinely cheaper (retention
+                                        # frees tiles the alias would pin):
+                                        # retry cold
+                                        m, spages = 0, []
+                                    else:
+                                        # cold could not fit either — and its
+                                        # _fits would evict the very prefix
+                                        # (a preemption victim's donated
+                                        # pages) that makes the eventual
+                                        # resume warm
+                                        self.stats[
+                                            "admission_backpressure"] += 1
+                                        break
+                            if not m:
+                                sc = (
+                                    self._ring_schedule(L)
+                                    if self.ring_tiles is not None
+                                    else self._paged_schedule(L, step_span=C)
+                                )
+                                need = lambda: (
+                                    self._committed(active, sched, pos)
+                                    + sc.remaining_peak(0)
+                                )
+                                gap = self._fits(need())
+                                if gap > 0 and self.preemptible:
+                                    gap = self._preempt_until(
+                                        need, rank, q, fetch, pool, pt, active,
+                                        sched, parr, pos, admit_pos, admit_seq,
+                                    )
+                                if gap > 0:
+                                    self.stats["admission_backpressure"] += 1
+                                    break
+                            if self.cross_pages is not None:
+                                nc = self._cross_admit(r, slot, ct, caches)
+                                if nc is None:
+                                    self.stats["admission_backpressure"] += 1
+                                    break
+                                caches = nc
+                            q.pop(r, clock)
+                            if r.preemptions:
+                                # a victim re-admitting (possibly
+                                # mid-prefill, no tokens)
+                                self.stats["resumes"] += 1
+                                if m:
+                                    self.stats["resume_warm_hits"] += 1
+                            if m:
+                                for i, p in enumerate(spages):
+                                    pt[slot, i] = p
+                                self.stats["prefix_hits"] += 1
+                                self.stats["prefix_hit_tokens"] += m
+                            elif self.ring_tiles is not None:
+                                # the fixed mod-window page set, allocated
+                                # up front — chunk streaming reuses the
+                                # slots in phase
+                                n_ring = min(self.ring_tiles,
+                                             -(-L // self.page))
+                                for t in range(n_ring):
+                                    pt[slot, t] = pool.alloc(own)
+                            active[slot] = r
+                            sched[slot] = sc
+                            parr[slot] = pr
+                            pos[slot] = m
+                            consumed[slot] = m
+                            admit_pos[slot] = m
+                            admit_seq[slot] = aseq
+                            aseq += 1
+                            remaining[slot] = r.max_new - len(r.generated)
+                    self.stats["max_concurrent"] = max(
+                        self.stats["max_concurrent"],
+                        sum(a is not None for a in active),
                     )
-                    toks = jnp.argmax(logits, -1).astype(jnp.int32)
-                    self.stats["decode_steps"] += 1
-                    self.stats["decode_tokens"] += len(dec_rows)
-                    sinks = []
-                    for slot in dec_rows:
-                        r = active[slot]
-                        sinks.append((r, slot))
-                        pos[slot] += 1
-                        remaining[slot] -= 1
-                        if remaining[slot] <= 0:
-                            self._free_all(pool, pt, slot, f"req{r.uid}")
-                            if ct is not None:
-                                self._release_cross(ct, slot, f"req{r.uid}")
-                            active[slot] = None
-                            sched[slot] = None
-                            parr[slot] = None
-                        else:
-                            self._free_dead(
-                                pool, pt, slot, sched[slot], int(pos[slot]),
-                                f"req{r.uid}",
-                            )
-                    self._stamp_emits(sinks, clock)
-                    fetch.push(toks, sinks)
-                    nxt = jnp.where(jnp.asarray(use_nxt), toks, nxt)
-                # (b) prompt chunks through the paged chunk grid: allocate
-                # the chunk's tiles, stream it into the pool, then free
-                # whatever the pattern says is already dead
-                for slot in chunk_rows:
-                    r = active[slot]
-                    t = int(chunk_t[slot])
-                    caches = self._ensure_writable(
-                        pool, pt, slot, int(pos[slot]), int(pos[slot]) + t,
-                        caches, f"req{r.uid}",
-                    )
-                    ctoks = np.zeros((1, C), np.int32)
-                    ctoks[0, :t] = parr[slot][
-                        consumed[slot] : consumed[slot] + t
+                    if not any(r is not None for r in active):
+                        clock += 1
+                        continue
+                    eligible = [
+                        s for s in range(B)
+                        if active[s] is not None
+                        and len(parr[s]) - consumed[s] <= 0
                     ]
-                    kv_live = _next_bucket(int(pos[slot]) + t, self.cache_len)
-                    logits1, caches = self.p_chunk_fn(
-                        self.params, caches, jnp.asarray(ctoks),
-                        _to_device(pt[slot : slot + 1]),
-                        jnp.int32(pos[slot]), jnp.int32(t), kv_live,
-                        ct=None if ct is None else _to_device(ct[slot : slot + 1]),
+                    use_nxt = np.zeros(B, bool)
+                    chunk_t = np.zeros(B, np.int32)
+                    budget = self.chunk_budget
+                    # interactive rows split the chunk budget ahead of batch
+                    # rows; the rotation keeps it fair within a class (and IS
+                    # the whole order under uniform priority / fifo scheduling)
+                    order = sorted(
+                        range(B),
+                        key=lambda s: (
+                            0 if self.fifo or active[s] is None
+                            else _PRIORITY_RANK[active[s].priority],
+                            (s - rr) % B,
+                        ),
                     )
-                    self.stats["chunk_calls"] += 1
-                    self.stats["prefill_tokens"] += t
-                    self.stats["prefill_flops"] += self._prefill_flop_count(
-                        int(pos[slot]), t
-                    )
-                    pos[slot] += t
-                    consumed[slot] += t
-                    if consumed[slot] == len(parr[slot]):
-                        self._cache_pages(parr[slot], pt, slot)
-                        tok1 = jnp.argmax(logits1).astype(jnp.int32)
-                        self._stamp_emits([(r, 0)], clock)
-                        fetch.push(tok1, [(r, 0)])
-                        nxt = nxt.at[slot].set(tok1)
-                        remaining[slot] -= 1
-                        if remaining[slot] <= 0:
-                            self._free_all(pool, pt, slot, f"req{r.uid}")
-                            if ct is not None:
-                                self._release_cross(ct, slot, f"req{r.uid}")
-                            active[slot] = None
-                            sched[slot] = None
-                            parr[slot] = None
+                    for slot in order:
+                        r = active[slot]
+                        if r is None:
                             continue
-                    self._free_dead(pool, pt, slot, sched[slot],
-                                    int(pos[slot]), f"req{r.uid}")
+                        rem_prompt = len(parr[slot]) - consumed[slot]
+                        if rem_prompt > 0:
+                            t = self._budget_draw(r, rem_prompt, budget)
+                            if t <= 0:
+                                continue
+                            chunk_t[slot] = t
+                            budget -= t
+                        else:
+                            use_nxt[slot] = True
+                    rr = (rr + 1) % B
+                    clock += 1
+                    self.stats["mixed_steps"] += 1
+                    dec_rows = [s for s in range(B) if use_nxt[s]]
+                    chunk_rows = [s for s in range(B) if chunk_t[s] > 0]
+                    if any(s not in dec_rows for s in eligible):
+                        self.stats["decode_stall_steps"] += 1
+                    if dec_rows and chunk_rows:
+                        self.stats["overlap_steps"] += 1
+                    # (a) paged decode wave: every decoding row advances
+                    # through the decode grid; non-decoding rows run with a
+                    # sentinel page-table row so their garbage write DROPS —
+                    # a mid-prompt row's frontier tile may alias a shared
+                    # prefix page, which an unmasked write would corrupt for
+                    # every sibling
+                    if dec_rows:
+                        if self.ring_tiles is not None:
+                            kv_live = None  # ring positions are unbounded
+                        else:
+                            hot = max(int(pos[s]) + 1 for s in dec_rows)
+                            kv_live = _next_bucket(hot, self.cache_len)
+                            self.stats["decode_kv_live_max"] = max(
+                                self.stats.get("decode_kv_live_max", 0),
+                                kv_live,
+                            )
+                        with self._phase("decode", rows=len(dec_rows),
+                                         kv_live=kv_live):
+                            for slot in dec_rows:
+                                caches = self._ensure_writable(
+                                    pool, pt, slot, int(pos[slot]),
+                                    int(pos[slot]) + 1, caches,
+                                    f"req{active[slot].uid}",
+                                )
+                            use = np.asarray(use_nxt)
+                            pt_wave = np.where(
+                                use[:, None], pt, np.int32(self.pool_pages)
+                            ).astype(np.int32)
+                            cross = ({} if ct is None
+                                     else {"ct": _to_device(ct)})
+                            logits, caches = self.p_decode_fn(
+                                self.params, caches, nxt[:, None],
+                                _to_device(pos), jnp.asarray(pt_wave), kv_live,
+                                **cross,
+                            )
+                            toks = jnp.argmax(logits, -1).astype(jnp.int32)
+                            self.stats["decode_steps"] += 1
+                            self.stats["decode_tokens"] += len(dec_rows)
+                            sinks = []
+                            for slot in dec_rows:
+                                r = active[slot]
+                                own = f"req{r.uid}"
+                                sinks.append((r, slot))
+                                pos[slot] += 1
+                                remaining[slot] -= 1
+                                if remaining[slot] <= 0:
+                                    self._free_all(pool, pt, slot, own)
+                                    if ct is not None:
+                                        self._release_cross(ct, slot, own)
+                                    active[slot] = None
+                                    sched[slot] = None
+                                    parr[slot] = None
+                                else:
+                                    self._free_dead(
+                                        pool, pt, slot, sched[slot],
+                                        int(pos[slot]), own,
+                                    )
+                            self._stamp_emits(sinks, clock)
+                            fetch.push(toks, sinks)
+                            nxt = jnp.where(jnp.asarray(use_nxt), toks, nxt)
+                    # (b) prompt chunks through the paged chunk grid: allocate
+                    # the chunk's tiles, stream it into the pool, then free
+                    # whatever the pattern says is already dead
+                    for slot in chunk_rows:
+                        r = active[slot]
+                        own = f"req{r.uid}"
+                        t = int(chunk_t[slot])
+                        p0 = int(pos[slot])
+                        kv_live = _next_bucket(p0 + t, self.cache_len)
+                        with self._phase("chunk", req=r.uid, tokens=t,
+                                         kv_live=kv_live):
+                            caches = self._ensure_writable(
+                                pool, pt, slot, p0, p0 + t, caches, own,
+                            )
+                            ctoks = np.zeros((1, C), np.int32)
+                            ctoks[0, :t] = parr[slot][
+                                consumed[slot] : consumed[slot] + t
+                            ]
+                            ct_row = (None if ct is None
+                                      else _to_device(ct[slot : slot + 1]))
+                            logits1, caches = self.p_chunk_fn(
+                                self.params, caches, jnp.asarray(ctoks),
+                                _to_device(pt[slot : slot + 1]),
+                                jnp.int32(p0), jnp.int32(t), kv_live,
+                                ct=ct_row,
+                            )
+                            self.stats["chunk_calls"] += 1
+                            self.stats["prefill_tokens"] += t
+                            self.stats["prefill_flops"] += (
+                                self._prefill_flop_count(p0, t)
+                            )
+                            pos[slot] += t
+                            consumed[slot] += t
+                            if consumed[slot] == len(parr[slot]):
+                                self._cache_pages(parr[slot], pt, slot)
+                                tok1 = jnp.argmax(logits1).astype(jnp.int32)
+                                self._stamp_emits([(r, 0)], clock)
+                                fetch.push(tok1, [(r, 0)])
+                                nxt = nxt.at[slot].set(tok1)
+                                remaining[slot] -= 1
+                                if remaining[slot] <= 0:
+                                    self._free_all(pool, pt, slot, own)
+                                    if ct is not None:
+                                        self._release_cross(ct, slot, own)
+                                    active[slot] = None
+                                    sched[slot] = None
+                                    parr[slot] = None
+                                    continue
+                            self._free_dead(pool, pt, slot, sched[slot],
+                                            int(pos[slot]), own)
         fetch.flush()
         self._pools = caches
         self._finish_paged_run(pool)

@@ -5,7 +5,9 @@ disaggregated)."""
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
+import time
 
 import jax.numpy as jnp
 import numpy as np
@@ -46,14 +48,17 @@ class Request:
     uid: int
     prompt: np.ndarray  # (S,) int32
     max_new: int
-    arrival: int = 0  # earliest engine step at which the request exists
+    arrival: int = 0  # engine steps: earliest step at which it exists
     priority: str = "interactive"  # scheduling class, see _PRIORITY_RANK
     generated: list[int] = dataclasses.field(default_factory=list)
     extras: dict = dataclasses.field(default_factory=dict)  # e.g. encdec frames
-    # SLO accounting, in engine-step clock units (reset by each run()):
+    # reset by each run(); SLO accounting in engine steps, not time:
     emit_clocks: list[int] = dataclasses.field(default_factory=list)
-    ttft: int | None = None  # first-token clock minus arrival
+    ttft: int | None = None  # engine steps: first-token step minus arrival
     preemptions: int = 0  # times this request was evicted and requeued
+    # seconds (time.perf_counter()): when the request first left the
+    # admission queue; a resumed victim keeps its first stamp
+    admitted: float | None = None
 
 
 class _AdmitQueue:
@@ -112,6 +117,8 @@ class _AdmitQueue:
                 if (not self.fifo and _PRIORITY_RANK[r.priority]
                         and self.rank(r, clock) == 0):
                     self.promotions += 1
+                if r.admitted is None:
+                    r.admitted = time.perf_counter()
                 del self._q[i]
                 return
         raise ValueError(f"pop of request {r.uid} not in queue")
@@ -150,10 +157,15 @@ class _AsyncTokens:
     resolves any record older than ``lag`` steps — so the host appends step
     t-1's values while step t's compute is already dispatched, and the
     per-token blocking ``np.asarray(argmax(...))`` sync disappears from the
-    steady-state loop.  ``flush()`` resolves everything (end of run)."""
+    steady-state loop.  ``flush()`` resolves everything (end of run).
 
-    def __init__(self, lag: int = 1):
+    ``phase`` is the engine's phase helper (``ServeLoop._phase``): each
+    resolve, where the host blocks on the device, runs inside its
+    ``"resolve"`` phase; without it resolves are untimed."""
+
+    def __init__(self, lag: int = 1, phase=None):
         self.lag = lag
+        self._phase = phase or (lambda name: contextlib.nullcontext())
         self._q: collections.deque = collections.deque()
 
     def push(self, dev, sinks: list[tuple[Request, int]]) -> None:
@@ -163,10 +175,11 @@ class _AsyncTokens:
             self._resolve()
 
     def _resolve(self) -> None:
-        dev, sinks = self._q.popleft()
-        vals = np.asarray(dev).reshape(-1)
-        for r, i in sinks:
-            r.generated.append(int(vals[i]))
+        with self._phase("resolve"):
+            dev, sinks = self._q.popleft()
+            vals = np.asarray(dev).reshape(-1)
+            for r, i in sinks:
+                r.generated.append(int(vals[i]))
 
     def flush(self) -> None:
         while self._q:
