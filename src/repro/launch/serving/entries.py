@@ -326,12 +326,15 @@ def make_paged_fns(
     jitted program of one bucket (built on first use), so a caller can
     ``.lower(...)`` it — to compile ahead or to inspect the compiled module.
 
-    All entry points donate the pools; the page tables are tiny replicated
-    int32 arrays refreshed from host state every call.  On a mesh with a
-    ``pages`` axis the pool's page rows are SHARDED over it — each device
-    holds the contiguous physical range the host allocator's matching shard
-    places into — while the page tables stay replicated (they are the
-    ownership record both sides read).
+    All entry points donate the pools, and the calls write them in place:
+    the layer scan carries the stacked pools and scatters each layer's new
+    rows into them (:func:`repro.models.transformer.run_stack`), so the
+    pools a call returns are its input buffers.  The page tables are tiny
+    replicated int32 arrays refreshed from host state every call.  On a
+    mesh with a ``pages`` axis the pool's page rows are SHARDED over it —
+    each device holds the contiguous physical range the host allocator's
+    matching shard places into — while the page tables stay replicated
+    (they are the ownership record both sides read).
 
     ``kv_dtype`` selects the pool storage width (bf16 | int8 | fp8_e4m3) —
     the entry points themselves are layout-agnostic (the caches tree flows

@@ -186,6 +186,7 @@ def _paged_kv_write(
     page: int,
     ring_tiles: int | None = None,
     scale: jax.Array | None = None,
+    layer: jax.Array | int | None = None,
 ):
     """Page-table-indirected masked scatter: token KV at absolute positions
     ``rows`` (B, C) lands at ``page_table[b, rows // page] * page + rows %
@@ -215,27 +216,41 @@ def _paged_kv_write(
     scatters through the SAME flat page-row index, so a page and its scales
     can never diverge — CoW copies, radix aliasing, rings, and shard
     transfers carry them as one unit.  Returns ``(pool, scale)`` in that
-    form, the pool alone otherwise (the PR-9 graph, bit-identical)."""
-    n_pages = pool.shape[0] // page
+    form, the pool alone otherwise.
+
+    ``layer`` selects the STACKED form: ``pool`` (and ``scale``) keep their
+    leading ``n_periods`` axis and the rows land at ``(layer, flat)`` — an
+    in-place scatter of B x C rows into the pool :func:`run_stack` carries
+    through its layer scan, with no copy of the layer's pool."""
+    lead = () if layer is None else (layer,)
+    n_rows = pool.shape[len(lead)]
     vt = rows // page
     if ring_tiles is not None:
         vt = vt % ring_tiles
     vt = jnp.clip(vt, 0, page_table.shape[1] - 1)
     phys = jnp.take_along_axis(page_table, vt, axis=1)
     flat = phys * page + rows % page
-    flat = jnp.where(valid & (phys < n_pages), flat, pool.shape[0])
+    flat = jnp.where(valid & (phys < n_rows // page), flat, n_rows).reshape(-1)
     if scale is None:
-        return pool.at[flat.reshape(-1)].set(
+        return pool.at[(*lead, flat)].set(
             new.astype(pool.dtype).reshape(-1, *new.shape[2:]), mode="drop"
         )
     qv, sc = quant.quantize_rows(new, pool.dtype)  # (B, C, KV, hd), (B, C, KV)
-    pool = pool.at[flat.reshape(-1)].set(
+    pool = pool.at[(*lead, flat)].set(
         qv.reshape(-1, *qv.shape[2:]), mode="drop"
     )
-    scale = scale.at[flat.reshape(-1)].set(
+    scale = scale.at[(*lead, flat)].set(
         sc.reshape(-1, *sc.shape[2:]).astype(scale.dtype), mode="drop"
     )
     return pool, scale
+
+
+def _layer_of(pool: jax.Array | None, layer: jax.Array | None):
+    """Layer ``layer`` of a stacked pool leaf (the leaf itself when
+    ``layer`` is None: the pool is already one layer's)."""
+    if pool is None or layer is None:
+        return pool
+    return jax.lax.dynamic_index_in_dim(pool, layer, axis=0, keepdims=False)
 
 
 def paged_copy_page(caches: dict, src: jax.Array, dst: jax.Array, page: int) -> dict:
@@ -273,6 +288,7 @@ def apply_attention(
     ntok: jax.Array | None = None,  # (B,) valid chunk tokens (mixed step)
     page_table: jax.Array | None = None,  # (B, n_vtiles) paged-cache indirection
     page: int | None = None,  # tokens per page (static; = the kv tile)
+    layer: jax.Array | None = None,  # this layer's index into stacked pools
 ):
     b, s, d = x.shape
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -310,8 +326,8 @@ def apply_attention(
         # a cross page, so copy-on-write can never trigger and every decoder
         # sharing the encoder output shares the physical pages outright.
         assert cache is not None and page is not None
-        kg = gather_pages(cache["k"], page_table, cfg.enc_seq, page)
-        vg = gather_pages(cache["v"], page_table, cfg.enc_seq, page)
+        kg = gather_pages(_layer_of(cache["k"], layer), page_table, cfg.enc_seq, page)
+        vg = gather_pages(_layer_of(cache["v"], layer), page_table, cfg.enc_seq, page)
         if mode == "decode":
             out = run_decode_attention(
                 q[:, 0], kg, vg, None, spec=spec, rt=rt
@@ -330,6 +346,9 @@ def apply_attention(
         # absolute tile j lives in slot j % ring_tiles, positions are
         # unbounded, and the fine masks window on absolute positions — the
         # paged replacement for the contiguous ``_ring_place`` path.
+        # With ``layer`` the pools are the whole stack :func:`run_stack`
+        # carries: the write lands at (layer, row) in place, and attention
+        # reads layer ``layer`` of the written pools.
         assert cache is not None and pos is not None and page is not None
         ring_tiles = ring_window = None
         if cfg.sliding_window:
@@ -344,35 +363,30 @@ def apply_attention(
         # flat page layout as K/V — absent for bf16 (see repro.core.quant)
         ksc, vsc = cache.get("k_scale"), cache.get("v_scale")
 
-        def write(kc, vc, ksc, vsc, rows, valid, ring=None):
+        def write(rows, valid, ring=None):
+            """Scatter the new rows into the pools; returns the written pools
+            and this layer's (K, V, K scale, V scale) for attention."""
+            w = functools.partial(
+                _paged_kv_write, rows=rows, valid=valid,
+                page_table=page_table, page=page, ring_tiles=ring, layer=layer,
+            )
             if ksc is None:
-                kc = _paged_kv_write(kc, k_new, rows, valid, page_table, page, ring)
-                vc = _paged_kv_write(vc, v_new, rows, valid, page_table, page, ring)
-                return kc, vc, None, None
-            kc, ksc = _paged_kv_write(
-                kc, k_new, rows, valid, page_table, page, ring, scale=ksc
-            )
-            vc, vsc = _paged_kv_write(
-                vc, v_new, rows, valid, page_table, page, ring, scale=vsc
-            )
-            return kc, vc, ksc, vsc
-
-        def pack(kc, vc, ksc, vsc):
-            out = {"k": kc, "v": vc}
-            if ksc is not None:
-                out["k_scale"], out["v_scale"] = ksc, vsc
-            return out
+                pools = {"k": w(kc, k_new), "v": w(vc, v_new)}
+            else:
+                (pk, pks), (pv, pvs) = w(kc, k_new, scale=ksc), w(vc, v_new, scale=vsc)
+                pools = {"k": pk, "v": pv, "k_scale": pks, "v_scale": pvs}
+            names = ("k", "v", "k_scale", "v_scale")
+            return pools, [_layer_of(pools.get(n), layer) for n in names]
 
         if mode == "mixed":
             assert ntok is not None
             rows = pos[:, None] + jnp.arange(s, dtype=jnp.int32)  # (B, C)
             valid = jnp.arange(s)[None, :] < ntok[:, None]
-            kc, vc, ksc, vsc = write(kc, vc, ksc, vsc, rows, valid, ring_tiles)
-            new_cache = pack(kc, vc, ksc, vsc)
+            new_cache, (kl, vl, ksl, vsl) = write(rows, valid, ring_tiles)
             out = run_paged_chunk_attention(
-                q, kc, vc, pos, ntok, page_table, page=page, spec=spec,
+                q, kl, vl, pos, ntok, page_table, page=page, spec=spec,
                 rt=rt, kv_live=kv_live, ring_window=ring_window,
-                ring_tiles=ring_tiles, k_scale=ksc, v_scale=vsc,
+                ring_tiles=ring_tiles, k_scale=ksl, v_scale=vsl,
             )
         elif mode == "decode":
             # every row writes at its own position; a retired slot's page
@@ -382,12 +396,11 @@ def apply_attention(
             # wave, with the page table enforcing ownership
             rows = pos[:, None]  # (B, 1)
             valid = jnp.ones_like(rows, bool)
-            kc, vc, ksc, vsc = write(kc, vc, ksc, vsc, rows, valid, ring_tiles)
-            new_cache = pack(kc, vc, ksc, vsc)
+            new_cache, (kl, vl, ksl, vsl) = write(rows, valid, ring_tiles)
             out = run_paged_decode_attention(
-                q[:, 0], kc, vc, pos + 1, page_table, page=page, spec=spec,
+                q[:, 0], kl, vl, pos + 1, page_table, page=page, spec=spec,
                 rt=rt, kv_live=kv_live, ring_window=ring_window,
-                ring_tiles=ring_tiles, k_scale=ksc, v_scale=vsc,
+                ring_tiles=ring_tiles, k_scale=ksl, v_scale=vsl,
             )[:, None]
         elif mode == "prefill":
             if ring_tiles is not None:
@@ -402,11 +415,10 @@ def apply_attention(
                 lengths if lengths is not None else jnp.full((b,), s, jnp.int32)
             )
             valid = jnp.arange(s)[None, :] < ln[:, None]
-            kc, vc, ksc, vsc = write(kc, vc, ksc, vsc, rows, valid)
-            new_cache = pack(kc, vc, ksc, vsc)
+            new_cache, (kl, vl, ksl, vsl) = write(rows, valid)
             out = run_paged_prefill_attention(
-                q, k_new, v_new, kc, vc, page_table, page=page, spec=spec,
-                rt=rt, k_scale=ksc, v_scale=vsc,
+                q, k_new, v_new, kl, vl, page_table, page=page, spec=spec,
+                rt=rt, k_scale=ksl, v_scale=vsl,
             )
         else:
             raise ValueError(f"paged caches have no {mode!r} mode")
@@ -523,8 +535,11 @@ def apply_slot(
     page_table: jax.Array | None = None,
     page: int | None = None,
     cross_table: jax.Array | None = None,
+    layer: jax.Array | None = None,
 ):
-    """One layer: pre-norm mixer + (optional cross-attn) + pre-norm FFN."""
+    """One layer: pre-norm mixer + (optional cross-attn) + pre-norm FFN.
+    ``layer`` is set when ``cache`` holds the whole stack of paged pools
+    (:func:`run_stack`'s carried form) and names this layer's index in it."""
     aux = jnp.zeros((), jnp.float32)
     new_cache: dict = {}
     hmix = _norm(sparams["mixer_norm"], cfg, x)
@@ -533,7 +548,7 @@ def apply_slot(
             sparams["attn"], cfg, hmix, rt, causal=causal, positions=positions,
             mode=mode, cache=None if cache is None else cache.get("attn"), pos=pos,
             lengths=lengths, attn_pattern=slot.attn_pattern, kv_live=kv_live,
-            ntok=ntok, page_table=page_table, page=page,
+            ntok=ntok, page_table=page_table, page=page, layer=layer,
         )
         if c is not None:
             new_cache["attn"] = c
@@ -560,7 +575,7 @@ def apply_slot(
             sparams["cross"], cfg, hx, rt, causal=False, positions=positions,
             mode=mode, cache=None if cache is None else cache.get("cross"), pos=pos,
             kv_source=enc_out, is_cross=True, use_rope=False,
-            page_table=cross_table, page=page,
+            page_table=cross_table, page=page, layer=layer,
         )
         if cc is not None:
             new_cache["cross"] = cc
@@ -608,9 +623,19 @@ def run_stack(
     page: int | None = None,  # tokens per page (static)
     cross_table: jax.Array | None = None,  # (B, n_ctiles) shared cross pages
 ):
-    """Scan the periodic layer pattern.  Returns (x, new_caches, aux_sum)."""
+    """Scan the periodic layer pattern.  Returns (x, new_caches, aux_sum).
 
-    def body(carry, per):
+    Paged pools (``caches`` with a ``page_table``) ride the scan's CARRY:
+    the scan runs over ``(layer_params, layer index)``, layer ``i`` scatters
+    its new rows into the stacked pools at ``(i, row)`` and its attention
+    reads ``pool[i]``, so the pools that leave the loop are the (donated)
+    input buffers written in place — no per-layer restack into a second
+    stacked buffer and no whole-pool copy after the loop.  Read-only cross
+    pools ride the carry unwritten.  Every other cache (contiguous per-slot
+    KV, mamba state) has a batch axis and goes through the scan's
+    ``xs``/``ys``; ``cfg.unroll_layers`` slices every cache per layer."""
+
+    def body(carry, per, layer=None):
         x, aux = carry
         p_params, p_cache = per
         new_cache = {}
@@ -622,7 +647,7 @@ def run_stack(
                 cache=None if p_cache is None else p_cache[key], pos=pos,
                 enc_out=enc_out, causal=causal, lengths=lengths, kv_live=kv_live,
                 ntok=ntok, page_table=page_table, page=page,
-                cross_table=cross_table,
+                cross_table=cross_table, layer=layer,
             )
             new_cache[key] = c
             aux = aux + a
@@ -644,7 +669,19 @@ def run_stack(
         new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *outs) if outs else {}
         return x, new_caches, aux
 
-    if caches is None:
+    if caches is not None and page_table is not None:
+        def carried(carry, per):
+            x, aux, pools = carry
+            p_params, i = per
+            (x, aux), pools = body((x, aux), (p_params, pools), layer=i)
+            return (x, aux, pools), None
+
+        n = jax.tree.leaves(layer_params)[0].shape[0]
+        (x, aux, new_caches), _ = jax.lax.scan(
+            carried, (x, jnp.zeros((), jnp.float32), caches),
+            (layer_params, jnp.arange(n, dtype=jnp.int32)),
+        )
+    elif caches is None:
         (x, aux), new_caches = jax.lax.scan(
             lambda c, p: body(c, (p, None)), (x, jnp.zeros((), jnp.float32)), layer_params
         )
@@ -857,7 +894,10 @@ def paged_pool_specs(
     attention slot, (n_periods, n_pages * page, KV, hd) — no batch axis, no
     per-slot ``cache_len`` reservation.  Resident HBM is the pool; per-request
     footprint is the pages its page table holds, so capacity prices at live
-    tiles instead of ``batch x cache_len``.  Sliding-window configs need no
+    tiles instead of ``batch x cache_len``.  The pools stay stacked over
+    periods through every call: :func:`run_stack` carries them through its
+    layer scan and writes layer ``i``'s rows in place at ``(i, row)``.
+    Sliding-window configs need no
     special layout here — the ring modulus lives in the page TABLE
     (mod-window slots), the pool is just pages.  Encoder-decoder stacks add a
     per-slot ``cross`` pool of ``cross_pages`` pages holding the encoder
@@ -990,8 +1030,8 @@ def paged_encode(
             v_new = _proj(ap, cfg, enc_out, "wv", kv).reshape(b, s_enc, kv, hd)
             if cfg.qk_norm:
                 k_new = rms_norm(k_new, ap["k_norm"], cfg.norm_eps)
-            kp = kp.at[i].set(_paged_kv_write(kp[i], k_new, rows, valid, ct, page))
-            vp = vp.at[i].set(_paged_kv_write(vp[i], v_new, rows, valid, ct, page))
+            kp = _paged_kv_write(kp, k_new, rows, valid, ct, page, layer=i)
+            vp = _paged_kv_write(vp, v_new, rows, valid, ct, page, layer=i)
         new_caches[key] = {**caches[key], "cross": {"k": kp, "v": vp}}
     return new_caches
 

@@ -16,6 +16,7 @@ file keeps it until it exits.  The ``fa.*`` kernels are called with
 
 from __future__ import annotations
 
+import dataclasses
 import os
 
 import jax
@@ -23,9 +24,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import registry
 from repro.core import quant
 from repro.kernels import flash_attention as fa
 from repro.kernels import monarch_bpmm as mk
+from repro.kernels import ops
+from test_pool_inplace import paged_lowerings, stacked_pool_ops
 
 KV, G, HD, PAGE = 8, 2, 128, 128  # qwen3-0.6b: 16 q heads over 8 kv heads
 N_PAGES = 256  # batch 8 x cache_len 4096 / page
@@ -132,3 +136,29 @@ def test_monarch_bpmm_compiles(one_chip, gin, gout):
         _spec(one_chip, (gout, gin, b, nb, nb), jnp.bfloat16),
         token_tile=tile, interpret=False,
     ))
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "prefill"])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_paged_programs_write_pool_in_place(one_chip, monkeypatch, kv_dtype,
+                                            program):
+    """Whole paged serve programs for the chip (qwen3-0.6b at published
+    widths, two layers, the cells' fused kernels): the layer scan carries
+    the stacked pool and scatters into it, so no copy, broadcast,
+    dynamic-update-slice or AllocateBuffer of the stacked pool's shape is
+    left in the compiled module.  The int8 pool's f32 scale leaves, [L,
+    rows, KV] and a sixteenth of the K/V bytes, are left out: at this
+    test's pool size XLA stages them through VMEM with a layout copy on the
+    way in and out (at the cells' 260-page pool it does not, in decode and
+    chunk)."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    n_layers, n_pages = 2, 64
+    cfg = dataclasses.replace(
+        registry.get("qwen3-0.6b+flash+butterfly_attn"), n_layers=n_layers
+    )
+    lower = paged_lowerings(
+        cfg, kv_dtype, n_pages, chunk=CHUNK, batch=BATCH, prompt=512,
+        device=next(iter(one_chip.device_set)),
+    )[program]
+    hlo = _compiled_text(lower())
+    assert stacked_pool_ops(hlo, n_layers, n_pages * PAGE, KV, HD) == []
