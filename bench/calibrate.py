@@ -5,10 +5,11 @@
         --control-seeds 3 [--fault page_shift] [--out FILE]
 
 In one process on the cell's chip: the loop is built and warmed once;
-then, for each seed, the benchmark's weights from that seed are served one
-round of the cell's traffic (its full load, so the mix's longest requests
-finish), and the same sample a run checks is compared with the plain
-reference.  The program's widest gap over the seeds is the lower reading.
+then, for each seed, the weights the cell's model code makes from that
+seed are served one round of the cell's traffic (its full load, so the
+mix's longest requests finish), and the same sample a run checks is
+compared with the plain reference.  The program's widest gap over the
+seeds is the lower reading.
 For the first ``--control-seeds`` seeds the control is read too: the
 reference itself computed with float8 e4m3 matmul operands, the precision
 below the bfloat16 the configuration serves in, whose first-ranked token
@@ -54,24 +55,23 @@ def calibrate(root: str, workload: str, seeds: list[int], control_seeds: int) ->
     """The program's and the control's readings over ``seeds``."""
     import harness
     import traffic
-    import weights
 
-    _, cell, config, mix = harness.load_cell(root, workload)
+    _, cell, config, mix, model = harness.load_cell(root, workload)
     devices = harness.require_chips(cell["chips"])
     limit = float(harness._read_json(os.path.join(
         root, "bench", "limits", f"{workload}.json"))["widest_logit_gap"]["limit"])
     harness.use_checkout_cache(root)
-    sess = harness.Session(config, mix, seeds[0], devices)
+    sess = harness.Session(config, mix, seeds[0], devices, model)
     for specs in sess.warm_specs():
         sess.serve(specs)
     rows = []
     for i, seed in enumerate(seeds):
         if i:
-            sess.params = weights.make_params(sess.shape, seed)
+            sess.params = model.make_params(sess.shape, seed)
             sess.loop.params = sess.params
         rd = sess.serve(traffic.make_round(mix, seed, 0, sess.shape.vocab, stream=1))
         sample = harness.check_sample(rd.requests, mix["check_requests"], seed)
-        prog = harness.served_gaps(sess.params, config, sample)
+        prog = harness.served_gaps(model, sess.params, config, sample)
         failed = sum(len(r.generated) != r.max_new for r in rd.requests)
         row = {"seed": seed, "round_s": rd.end - rd.submit,
                "served": sum(r["tokens"] for r in prog),
@@ -80,7 +80,8 @@ def calibrate(root: str, workload: str, seeds: list[int], control_seeds: int) ->
                "program_passes": harness.verdict(prog, failed, limit),
                "finite": all(r["finite"] for r in prog), "failed": failed}
         if i < control_seeds:
-            ctl = harness.served_gaps(sess.params, config, sample, control=True)
+            ctl = harness.served_gaps(model, sess.params, config, sample,
+                                      control=True)
             row["control"] = max(r["widest"] for r in ctl)
             row["control_mismatch"] = sum(r["mismatch"] for r in ctl)
             row["control_passes"] = harness.verdict(ctl, 0, limit)

@@ -5,7 +5,20 @@ served tokens against the plain reference, print one result line.
 Everything that belongs to a configuration, a traffic mix, a metric or a
 cell's limits is a file under ``bench/`` found by the name
 ``BENCHMARK.json`` gives it: ``configs/<file>``, ``traffic/<mix>.json``,
-``metrics/<metric>.py`` and ``limits/<cell>.json``.
+``metrics/<metric>.py`` and ``limits/<cell>.json``.  A configuration's
+model code is ``models/<model_code>.py``, named by the file's top-level
+``model_code`` (``qwen3`` where it names none); it provides
+:data:`MODEL_FUNCTIONS`:
+
+* ``shape(config)``: the sizes a run's metrics read (``Run.shape``), with
+  ``.vocab`` at least;
+* ``program_config(config)``: the program's ``ModelConfig``, checked
+  against what the file states;
+* ``make_params(shape, seed)``: the weights from the seed, in the
+  program's parameter layout (set-up compares it, :func:`check_layout`);
+* ``logits_at(params, config, tokens, read, control=False)``: the plain
+  float32 reference's logits at positions ``read`` of ``tokens`` (with
+  ``control``, the comparison's lower-precision control).
 """
 
 from __future__ import annotations
@@ -22,23 +35,12 @@ import time
 
 import numpy as np
 
-import counts
 import traffic
 
-__all__ = ["Stamped", "Run", "CompileCounter", "load_cell", "run_cell"]
+__all__ = ["Stamped", "Run", "CompileCounter", "load_cell", "load_model",
+           "run_cell"]
 
-HF_FIELDS = {  # configuration file key -> program ModelConfig field
-    "num_hidden_layers": ("n_layers", int),
-    "hidden_size": ("d_model", int),
-    "vocab_size": ("vocab", int),
-    "num_attention_heads": ("n_heads", int),
-    "num_key_value_heads": ("n_kv_heads", int),
-    "head_dim": ("head_dim", int),
-    "intermediate_size": ("d_ff", int),
-    "rope_theta": ("rope_theta", float),
-    "rms_norm_eps": ("norm_eps", float),
-}
-
+MODEL_FUNCTIONS = ("shape", "program_config", "make_params", "logits_at")
 
 TRACE_SECONDS = 8.0  # a traced run traces whole rounds until this much time
 
@@ -117,7 +119,7 @@ class Run:
     """What a metric reader sees of one run."""
 
     mix: dict
-    shape: counts.ModelShape
+    shape: object  # the model code's shape(config)
     peaks: dict
     chips: int
     setup_s: float
@@ -137,8 +139,30 @@ def _read_json(path: str) -> dict:
         return json.load(f)
 
 
-def load_cell(root: str, name: str) -> tuple[dict, dict, dict, dict]:
-    """(BENCHMARK.json, cell, configuration file, traffic mix) of a cell."""
+def _load_file(path: str, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_model(root: str, config: dict):
+    """The configuration's model code, ``bench/models/<model_code>.py``."""
+    code = config.get("model_code", "qwen3")
+    path = os.path.join(root, "bench", "models", f"{code}.py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"configuration {config.get('name')!r} names "
+                                f"model code {code!r}: no file {path}")
+    mod = _load_file(path, f"model_{code}")
+    missing = [f for f in MODEL_FUNCTIONS if not callable(getattr(mod, f, None))]
+    if missing:
+        raise AttributeError(f"{path} lacks {missing}")
+    return mod
+
+
+def load_cell(root: str, name: str) -> tuple[dict, dict, dict, dict, object]:
+    """(BENCHMARK.json, cell, configuration file, traffic mix, model code)
+    of a cell."""
     bench = _read_json(os.path.join(root, "BENCHMARK.json"))
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -147,7 +171,7 @@ def load_cell(root: str, name: str) -> tuple[dict, dict, dict, dict]:
     cfg_entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
     config = _read_json(os.path.join(root, cfg_entry["file"]))
     mix = traffic.load(cell["traffic"], os.path.join(root, "bench"))
-    return bench, cell, config, mix
+    return bench, cell, config, mix, load_model(root, config)
 
 
 def cell_metrics(bench: dict, cell: dict, per_layer: bool) -> list[dict]:
@@ -167,32 +191,24 @@ def cell_metrics(bench: dict, cell: dict, per_layer: bool) -> list[dict]:
 def read_metric(root: str, name: str, run: Run):
     """Load ``bench/metrics/<name>.py`` and call its ``read(run)``."""
     path = os.path.join(root, "bench", "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(run)
+    return _load_file(path, f"metric_{name}").read(run)
 
 
-def model_config(config: dict):
-    """The program's ModelConfig: the registry entry the file names, with
-    every size taken from the file."""
-    from repro.configs import registry
+def check_layout(params, abstract) -> None:
+    """Raise unless ``params`` has the program's tree, shapes and dtypes."""
+    import jax
 
-    s = config["serving"]
-    mc = registry.get(s["registry"])
-    mc = dataclasses.replace(mc, **{
-        field: cast(config[key]) for key, (field, cast) in HF_FIELDS.items()})
-    spec = mc.attention_spec
-    if (spec.impl, spec.pattern) != (s["attn_impl"], s["attn_pattern"]):
-        raise ValueError(f"{s['registry']} runs {spec.impl}/{spec.pattern}, "
-                         f"the file states {s['attn_impl']}/{s['attn_pattern']}")
-    if (mc.dtype, mc.param_dtype) != (s["compute_dtype"], s["param_dtype"]):
-        raise ValueError(f"{s['registry']} computes in {mc.dtype} over "
-                         f"{mc.param_dtype}, the file states otherwise")
-    linears = "dense" if mc.butterfly.impl == "dense" else "bpmm"
-    if linears != s["linears"]:
-        raise ValueError(f"{s['registry']} has {linears} linears")
-    return mc
+    def table(tree):
+        flat, _ = jax.tree_util.tree_flatten_with_path(tree)
+        return {jax.tree_util.keystr(p): (tuple(a.shape), str(a.dtype))
+                for p, a in flat}
+
+    mine, theirs = table(params), table(abstract)
+    if mine != theirs:
+        diff = sorted(set(mine.items()) ^ set(theirs.items()))
+        raise RuntimeError(
+            "benchmark weights do not match the program's parameter layout: "
+            f"{diff[:6]}")
 
 
 def use_checkout_cache(root: str) -> None:
@@ -219,30 +235,29 @@ def require_chips(n: int):
 
 class Session:
     """The system under test, built from the seed: weights, the loop, and
-    the round driver."""
+    the round driver.  The mix's ``serving.loop`` object, where it has
+    one, adds its keys to the loop's keyword arguments."""
 
-    def __init__(self, config: dict, mix: dict, seed: int, devices):
+    def __init__(self, config: dict, mix: dict, seed: int, devices, model):
         import jax
 
         from repro.launch.mesh import make_mesh
         from repro.launch.serving import ServeLoop
         from repro.models import model as M
 
-        import weights
-
         self.mix, self.seed = mix, seed
-        self.shape = counts.ModelShape.from_config(config)
-        mc = model_config(config)
+        self.shape = model.shape(config)
+        mc = model.program_config(config)
         mesh = make_mesh((1, 1), ("data", "model"), devices=devices[:1])
-        self.params = weights.make_params(self.shape, seed)
-        weights.check_layout(self.params, M.abstract_params(mc))
+        self.params = model.make_params(self.shape, seed)
+        check_layout(self.params, M.abstract_params(mc))
         sv, s = mix["serving"], config["serving"]
         self.loop = ServeLoop(
             mc, mesh, self.params, batch=sv["batch"],
             cache_len=sv["cache_len"], attn_impl=s["attn_impl"],
             attn_pattern=s["attn_pattern"], chunked=True,
             chunk_size=sv["chunk"], paged=True, pool_pages=sv["pool_pages"],
-            kv_dtype=s["kv_dtype"],
+            kv_dtype=s["kv_dtype"], **sv.get("loop", {}),
         )
         if self.loop.page != s["tile"]:
             raise ValueError(f"pages of {self.loop.page} tokens, the file "
@@ -305,21 +320,21 @@ def _bucket(n: int, cap: int, floor: int = 8) -> int:
     return min(b, cap)
 
 
-def served_gaps(params, config: dict, requests: list, control: bool = False):
+def served_gaps(model, params, config: dict, requests: list,
+                control: bool = False):
     """Per request: the gap by which each served token's reference logit
-    lies below the reference's best at that position (for ``control``, the
-    token the float8 pass ranks first), and whether the tokens agree."""
-    import reference
-
+    (``model.logits_at``) lies below the reference's best at that position
+    (for ``control``, the token the control ranks first), and whether the
+    tokens agree."""
     out = []
     for r in requests:
         gen = np.asarray(list(r.generated), np.int64)
         seq = np.concatenate([np.asarray(r.prompt, np.int32), gen[:-1].astype(np.int32)])
         read = np.arange(len(r.prompt) - 1, len(seq))
-        ref = reference.logits_at(params, config, seq, read)
+        ref = model.logits_at(params, config, seq, read)
         best = ref.max(-1)
         if control:
-            pick = reference.logits_at(params, config, seq, read, control=True).argmax(-1)
+            pick = model.logits_at(params, config, seq, read, control=True).argmax(-1)
         else:
             pick = gen
         gap = best - ref[np.arange(len(read)), pick]
@@ -383,7 +398,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float, trace: bool,
              t_start: float) -> dict:
     """One run; returns the result object (its ``correct`` says whether the
     served tokens passed the comparison)."""
-    bench, cell, config, mix = load_cell(root, workload)
+    bench, cell, config, mix, model = load_cell(root, workload)
     import jax
 
     devices = require_chips(cell["chips"])
@@ -396,7 +411,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float, trace: bool,
     limits = _read_json(os.path.join(root, "bench", "limits", f"{workload}.json"))
 
     with CompileCounter() as builds:
-        sess = Session(config, mix, seed, devices)
+        sess = Session(config, mix, seed, devices, model)
         for specs in sess.warm_specs():
             sess.serve(specs)
         # what set-up made lives to the end: keep it out of the collector's
@@ -514,7 +529,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float, trace: bool,
     del sess, run, rounds
     gc.collect()
     t0 = time.perf_counter()
-    rows = served_gaps(params, config, sample)
+    rows = served_gaps(model, params, config, sample)
     widest = max((r["widest"] for r in rows), default=float("inf"))
     limit = float(limits["widest_logit_gap"]["limit"])
     result["correct"] = verdict(rows, result["failed"], limit)

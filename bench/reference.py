@@ -24,7 +24,9 @@ bfloat16 the configuration serves in.  It is the comparison's control and
 never runs inside a benchmark run.
 
 Nothing here imports the program: the weights are the benchmark's own
-arrays, read as data.
+arrays, read as data.  The building blocks (:func:`mm`, :func:`rms`,
+:func:`rope`, :func:`attention`, :func:`pad`) are public for the references
+of other models' code under ``bench/models/``.
 """
 
 from __future__ import annotations
@@ -36,23 +38,24 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["monarch_dense", "butterfly_tiles", "logits_at"]
+__all__ = ["q8", "mm", "monarch_dense", "butterfly_tiles", "rms", "rope",
+           "attention", "pad", "logits_at"]
 
 HI = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0  # largest finite float8_e4m3fn
 
 
-def _q8(x: jax.Array, axis: int) -> jax.Array:
+def q8(x: jax.Array, axis: int) -> jax.Array:
     """Round to float8 e4m3 with one scale per slice along ``axis``."""
     s = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / F8_MAX
     s = jnp.where(s > 0, s, 1.0)
     return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
 
 
-def _mm(a, w, control: bool):
+def mm(a, w, control: bool):
     """a (..., din) @ w (din, dout)."""
     if control:
-        a, w = _q8(a, -1), _q8(w, 0)
+        a, w = q8(a, -1), q8(w, 0)
     return jnp.matmul(a, w, precision=HI)
 
 
@@ -87,11 +90,11 @@ def butterfly_tiles(n_tiles: int, pattern: str) -> np.ndarray:
     return out
 
 
-def _rms(x, w, eps):
+def rms(x, w, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * (1.0 + w)
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """x (T, H, hd), half-split rotation (transformers' rotate_half)."""
     half = x.shape[-1] // 2
     inv = 1.0 / (theta ** (np.arange(0, 2 * half, 2, dtype=np.float32) / (2 * half)))
@@ -101,7 +104,7 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _attention(q, k, v, table, tile, control):
+def attention(q, k, v, table, tile, control):
     """q (T, H, hd), k/v (T, KV, hd), T a multiple of ``tile``; ``table``
     from :func:`butterfly_tiles`."""
     t, h, hd = q.shape
@@ -113,7 +116,7 @@ def _attention(q, k, v, table, tile, control):
     kt = k.reshape(nt, tile, kvh, hd)[idx]  # (nt, L, tile, KV, hd)
     vt = v.reshape(nt, tile, kvh, hd)[idx]
     if control:
-        qt, kt = _q8(qt, -1), _q8(kt, -1)
+        qt, kt = q8(qt, -1), q8(kt, -1)
     s = jnp.einsum("iqkgd,ilskd->ikgqls", qt, kt, precision=HI) / math.sqrt(hd)
     qpos = np.arange(nt)[:, None, None, None] * tile + np.arange(tile)[None, :, None, None]
     kpos = table[:, None, :, None] * tile + np.arange(tile)[None, None, None, :]
@@ -123,7 +126,7 @@ def _attention(q, k, v, table, tile, control):
     p = jax.nn.softmax(s.reshape(*s.shape[:-2], lw), axis=-1)
     vv = vt.transpose(0, 3, 1, 2, 4).reshape(nt, kvh, lw, hd)  # (nt, KV, L*tile, hd)
     if control:
-        p, vv = _q8(p, -1), _q8(vv, -2)
+        p, vv = q8(p, -1), q8(vv, -2)
     o = jnp.einsum("ikgqm,ikmd->iqkgd", p, vv, precision=HI)
     return o.reshape(t, h, hd)
 
@@ -146,22 +149,22 @@ def _forward(params, tokens, read, *, shape, control):
 
     def layer(x, p):
         a = p["attn"]
-        h = _rms(x, p["mixer_norm"]["w"], eps)
-        q = _mm(h, weight(a, "wq"), control).reshape(t, heads, hd)
-        k = _mm(h, weight(a, "wk"), control).reshape(t, kvh, hd)
-        v = _mm(h, weight(a, "wv"), control).reshape(t, kvh, hd)
-        q = _rope(_rms(q, a["q_norm"], eps), pos, theta)
-        k = _rope(_rms(k, a["k_norm"], eps), pos, theta)
-        o = _attention(q, k, v, table, tile, control).reshape(t, heads * hd)
-        x = x + _mm(o, weight(a, "wo"), control)
+        h = rms(x, p["mixer_norm"]["w"], eps)
+        q = mm(h, weight(a, "wq"), control).reshape(t, heads, hd)
+        k = mm(h, weight(a, "wk"), control).reshape(t, kvh, hd)
+        v = mm(h, weight(a, "wv"), control).reshape(t, kvh, hd)
+        q = rope(rms(q, a["q_norm"], eps), pos, theta)
+        k = rope(rms(k, a["k_norm"], eps), pos, theta)
+        o = attention(q, k, v, table, tile, control).reshape(t, heads * hd)
+        x = x + mm(o, weight(a, "wo"), control)
         f = p["ffn"]
-        h = _rms(x, p["ffn_norm"]["w"], eps)
-        u = jax.nn.silu(_mm(h, weight(f, "w1"), control)) * _mm(h, weight(f, "w3"), control)
-        return x + _mm(u, weight(f, "w2"), control), None
+        h = rms(x, p["ffn_norm"]["w"], eps)
+        u = jax.nn.silu(mm(h, weight(f, "w1"), control)) * mm(h, weight(f, "w3"), control)
+        return x + mm(u, weight(f, "w2"), control), None
 
     x, _ = jax.lax.scan(layer, x, lp)
-    x = _rms(x[read], params["final_norm"]["w"][0], eps)
-    return _mm(x, params["head"], control)
+    x = rms(x[read], params["final_norm"]["w"][0], eps)
+    return mm(x, params["head"], control)
 
 
 def _bucket(n: int, floor: int) -> int:
@@ -171,12 +174,24 @@ def _bucket(n: int, floor: int) -> int:
     return b
 
 
+def pad(tokens: np.ndarray, read: np.ndarray, tile: int) -> tuple[jax.Array, jax.Array]:
+    """``tokens`` and ``read`` padded to powers of two, so that few lengths
+    compile: the padding sits after every read position, where causal
+    attention never sees it, and repeats the last read position."""
+    t = _bucket(len(tokens), max(tile, 128))
+    tok = np.zeros(t, np.int32)
+    tok[: len(tokens)] = tokens
+    r = _bucket(len(read), 8)
+    rd = np.full(r, read[-1], np.int32)
+    rd[: len(read)] = read
+    return jnp.asarray(tok), jnp.asarray(rd)
+
+
 def logits_at(params, cfg: dict, tokens: np.ndarray, read: np.ndarray,
               control: bool = False) -> np.ndarray:
     """Float32 logits at positions ``read`` of the sequence ``tokens``,
-    from the configuration file ``cfg`` and the weights ``params``.
-    Lengths are padded to powers of two (padding sits after every read
-    position, so causal attention never sees it)."""
+    from the configuration file ``cfg`` and the weights ``params``
+    (lengths padded by :func:`pad`)."""
     s = cfg["serving"]
     tile = s["tile"]
     d, heads, hd = cfg["hidden_size"], cfg["num_attention_heads"], cfg["head_dim"]
@@ -186,13 +201,7 @@ def logits_at(params, cfg: dict, tokens: np.ndarray, read: np.ndarray,
             ("w2", (ff, d)))
     shape = (cfg["num_hidden_layers"], heads, kvh, hd, float(cfg["rms_norm_eps"]),
              float(cfg["rope_theta"]), tile, s["attn_pattern"], s["linears"], dims)
-    t = _bucket(len(tokens), max(tile, 128))
-    tok = np.zeros(t, np.int32)
-    tok[: len(tokens)] = tokens
-    r = _bucket(len(read), 8)
-    rd = np.full(r, read[-1], np.int32)
-    rd[: len(read)] = read
-    out = _forward(params, jnp.asarray(tok), jnp.asarray(rd), shape=shape,
-                   control=control)
+    tok, rd = pad(tokens, read, tile)
+    out = _forward(params, tok, rd, shape=shape, control=control)
     return np.asarray(out, np.float32)[: len(read)]
 

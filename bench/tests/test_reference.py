@@ -15,7 +15,7 @@ def test_attention_matches_masked_softmax(pattern):
     k = rng.normal(size=(n, kvh, hd)).astype(np.float32)
     v = rng.normal(size=(n, kvh, hd)).astype(np.float32)
     table = reference.butterfly_tiles(n // tile, pattern)
-    got = np.asarray(reference._attention(q, k, v, table, tile, False))
+    got = np.asarray(reference.attention(q, k, v, table, tile, False))
 
     pos = np.arange(n)
     m = pos[None, :] <= pos[:, None]

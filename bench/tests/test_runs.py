@@ -1,8 +1,9 @@
 """Whole runs of a toy-width cell on the CPU, past the harness's look for a
-chip: a sound run is correct; a cell, a mix and a metric added as files
-are found by name; a token altered where the loop produces it, a pool read
-one page off, and the float8 control in the program's place all fail the
-comparison."""
+chip: a sound run is correct, for qwen3 and for a second family whose
+model code and configuration were added as files; a cell, a mix and a
+metric added as files are found by name; a token altered where the loop
+produces it, a pool read one page off, and the float8 control in the
+program's place all fail the comparison."""
 
 import json
 import os
@@ -28,8 +29,14 @@ def _run(root, cell="tiny.mix", seed=11, trace=False):
     return harness.run_cell(root, cell, seed, 0.5, trace, time.perf_counter())
 
 
-def test_sound_run_is_correct(tmp_path):
+def _root(tmp_path, family):
     root = tiny.make_root(str(tmp_path), limit=0.05)
+    return tiny.use_dense_gqa(root) if family == "dense_gqa" else root
+
+
+@pytest.mark.parametrize("family", ["qwen3", "dense_gqa"])
+def test_sound_run_is_correct(tmp_path, family):
+    root = _root(tmp_path, family)
     res = _run(root, seed=2**31 + 3)
     assert res["correct"] and res["failed"] == 0 and res["attempted"] >= 3
     assert set(res["metrics"]) == {"output_tok_s", "itl_p95_ms", "ttft_p95_ms", "setup_s"}
@@ -74,8 +81,9 @@ def test_cell_mix_and_metric_added_as_files(tmp_path):
     assert "output_tok_s" not in res["metrics"]
 
 
-def test_altered_token_is_not_correct(tmp_path, monkeypatch):
-    root = tiny.make_root(str(tmp_path), limit=0.05)
+@pytest.mark.parametrize("family", ["qwen3", "dense_gqa"])
+def test_altered_token_is_not_correct(tmp_path, monkeypatch, family):
+    root = _root(tmp_path, family)
     push = queueing._AsyncTokens.push
     calls = []
 

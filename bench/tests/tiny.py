@@ -1,5 +1,6 @@
 """A tiny cell for the CPU tests: the qwen3 configuration file at toy
-widths, a short mix, and a checkout-like directory holding them."""
+widths, a short mix, and a checkout-like directory holding them; or, with
+:func:`use_dense_gqa`, a second model family added as files."""
 
 from __future__ import annotations
 
@@ -7,7 +8,8 @@ import json
 import os
 import shutil
 
-BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
 
 TINY = {"num_hidden_layers": 2, "hidden_size": 64, "vocab_size": 512,
         "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
@@ -25,7 +27,9 @@ def make_root(tmp: str, config: str = "qwen3-0.6b-bfly", limit: float = 1.0) -> 
     os.makedirs(os.path.join(root, "bench", "configs"))
     os.makedirs(os.path.join(root, "bench", "traffic"))
     os.makedirs(os.path.join(root, "bench", "limits"))
-    shutil.copytree(os.path.join(BENCH, "metrics"), os.path.join(root, "bench", "metrics"))
+    for d in ("metrics", "models"):
+        shutil.copytree(os.path.join(BENCH, d), os.path.join(root, "bench", d),
+                        ignore=shutil.ignore_patterns("__pycache__"))
     with open(os.path.join(BENCH, "configs", f"{config}.json")) as f:
         cfg = json.load(f)
     cfg.update(TINY)
@@ -46,6 +50,21 @@ def make_root(tmp: str, config: str = "qwen3-0.6b-bfly", limit: float = 1.0) -> 
     for m in bench["end_to_end"] + bench["per_layer"]:
         m.pop("workloads", None)
     _dump(bench, root, "BENCHMARK.json")
+    return root
+
+
+def use_dense_gqa(root: str) -> str:
+    """Turn ``root``'s tiny cell into one of the test-only dense GQA family
+    (``dense_gqa.py``, the program's ``yi-6b`` entry): its model code and
+    its configuration file, and nothing else, are added to the root."""
+    shutil.copy(os.path.join(HERE, "dense_gqa.py"),
+                os.path.join(root, "bench", "models", "dense_gqa.py"))
+    path = os.path.join(root, "bench", "configs", "tiny.json")
+    with open(path) as f:
+        cfg = json.load(f)
+    cfg.update(name="dense-gqa-tiny", model_code="dense_gqa", rope_theta=5000000)
+    cfg["serving"] = dict(cfg["serving"], registry="yi-6b+flash+butterfly_attn")
+    _dump(cfg, root, "bench/configs/tiny.json")
     return root
 
 

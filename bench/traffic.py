@@ -16,7 +16,8 @@ Keys of a mix file:
 * ``source``: the public trace the ranges are fitted to;
 * ``prompt_len``, ``output_len``: [lo, hi] token ranges, log-uniform;
 * ``round_requests``: requests per round;
-* ``serving``: the loop's batch, cache length, chunk and page pool;
+* ``serving``: the loop's batch, cache length, chunk and page pool, and
+  optionally ``loop``, further keyword arguments of ``ServeLoop``;
 * ``check_requests``: how many of the window's requests the reference checks.
 """
 

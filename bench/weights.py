@@ -1,12 +1,13 @@
 """Weights made by the benchmark from ``--seed``, in the layout the program
 serves.
 
-One jitted call on the device makes every leaf from the seed, in the
-program's parameter dtype (float32 masters).  The tree is built from the
-configuration file's sizes alone; :func:`check_layout` compares it with
-the program's own abstract tree, so a layout change in the program fails
-set-up instead of serving something else.  The plain reference reads the
-same arrays as data.
+:func:`build` makes every leaf of a tree of (shape, scale) from the seed in
+one jitted call on the device, in the program's parameter dtype (float32
+masters); a model's code gives the tree.  :func:`layout` is Qwen3's, built
+from the configuration file's sizes alone.  Set-up compares the weights
+with the program's own abstract tree (``harness.check_layout``), so a
+layout change in the program fails set-up instead of serving something
+else.  The plain reference reads the same arrays as data.
 """
 
 from __future__ import annotations
@@ -19,7 +20,7 @@ import numpy as np
 
 from counts import ModelShape, bpmm_plan, linear_sites
 
-__all__ = ["seed_key", "layout", "make_params", "check_layout"]
+__all__ = ["seed_key", "layout", "build", "make_params"]
 
 
 def seed_key(seed: int, salt: int = 0) -> jax.Array:
@@ -68,13 +69,14 @@ def _is_leaf(x) -> bool:
     return isinstance(x, tuple) and len(x) == 2 and isinstance(x[0], tuple)
 
 
-def make_params(ms: ModelShape, seed: int, dtype=jnp.float32):
-    """Every leaf from the seed, in one jitted call on the default device."""
-    spec = layout(ms)
+def build(spec: dict, seed: int, dtype=jnp.float32):
+    """Every leaf of ``spec``, a tree of (shape, scale) with scale 0 for
+    zeros, from the seed, in one jitted call on the default device.  Leaf
+    ``i`` in flattening order draws from the seed's key folded with ``i``."""
     leaves, treedef = jax.tree.flatten(spec, is_leaf=_is_leaf)
 
     @jax.jit
-    def build(key):
+    def make(key):
         out = []
         for i, (shape, scale) in enumerate(leaves):
             if scale == 0.0:
@@ -84,19 +86,9 @@ def make_params(ms: ModelShape, seed: int, dtype=jnp.float32):
                 out.append(jax.random.normal(k, shape, dtype) * scale)
         return jax.tree.unflatten(treedef, out)
 
-    return build(seed_key(seed))
+    return make(seed_key(seed))
 
 
-def check_layout(params, abstract) -> None:
-    """Raise unless ``params`` has the program's tree, shapes and dtypes."""
-    def table(tree):
-        flat, _ = jax.tree_util.tree_flatten_with_path(tree)
-        return {jax.tree_util.keystr(p): (tuple(a.shape), str(a.dtype))
-                for p, a in flat}
-
-    mine, theirs = table(params), table(abstract)
-    if mine != theirs:
-        diff = sorted(set(mine.items()) ^ set(theirs.items()))
-        raise RuntimeError(
-            "benchmark weights do not match the program's parameter layout: "
-            f"{diff[:6]}")
+def make_params(ms: ModelShape, seed: int, dtype=jnp.float32):
+    """Qwen3's weights from the seed."""
+    return build(layout(ms), seed, dtype)
