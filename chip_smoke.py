@@ -350,9 +350,9 @@ def run_four_chips(cfg, seed, clock, devices):
     leaf = jax.tree.leaves(dis._pools)[0]
     seen = {}
     for sh in leaf.addressable_shards:
-        lo, hi, _ = sh.index[1].indices(rows)
+        lo, hi, _ = sh.index[2].indices(rows)  # (L, KV, rows, hd): rows shard
         seen[sh.device.id] = (lo // dis.page, hi // dis.page)
-        if sh.data.shape[1] != hi - lo:
+        if sh.data.shape[2] != hi - lo:
             raise RuntimeError(f"shard on {sh.device} holds {sh.data.shape}")
     ranges = sorted(seen.values())
     want = [(i * dis.pool_pages // 4, (i + 1) * dis.pool_pages // 4) for i in range(4)]

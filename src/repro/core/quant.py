@@ -8,10 +8,11 @@ filled page, so a true per-page scale would have to requantize every
 previously written row on each append (either an extra gather/rescale/scatter
 per decode step or compounding rounding error across up to ``page``
 requantizations).  Per-row scales make every write independent, and because
-the scale rows live in the same ``n_pages * page`` flat layout as the KV rows
-they ride the page tables for free — copy-on-write page copies, radix prefix
-aliasing, mod-window rings, and the sharded pool's ownership ``transfer()``
-all carry scales without knowing they exist.
+the scale leaves are paged like the KV rows (stacked by layer, one lane row
+per page and kv head: (L, KV, n_pages, 1, page) beside the KV leaves' (L,
+KV, n_pages * page, hd)) they ride the page tables for free — copy-on-write
+page copies, radix prefix aliasing, mod-window rings, and the sharded pool's
+ownership ``transfer()`` all carry scales without knowing they exist.
 
 Schemes (both symmetric, zero-point-free — attention rows are centred):
 

@@ -33,11 +33,17 @@ Decode kernel
     arrives as a per-row additive bias row.
 
 Layouts (pre-padded by :mod:`repro.kernels.ops`):
-    prefill  q: (BK, G, Sq, D)   k, v: (BK, Skv, D)   y: (BK, G, Sq, D)
+    prefill  q: (BK, G, Sq, D)   k, v: (L, BK, Skv, D)   y: (BK, G, Sq, D)
+             layer: (1,) int32, the index into k/v's leading axis
              kv_index, step_live: (q_tiles, max_live) int32
     decode   q: (BK, Gp, D)      k, v: (BK, Skv, D)   bias: (BK, Skv)
              kv_index, step_live: (BK, max_live) int32
-    with BK = batch * kv_heads, G the GQA group, D the padded head dim.
+    with BK = batch * kv_heads, G the GQA group, D the padded head dim (for
+    the paged pool, which is never padded, the model's head width).
+The paged kernels (and ``mha_prefill``, which serves the paged admission
+prefill too) read the pool where it is stored, stacked by layer: the layer
+is one more scalar-prefetch operand and the leading coordinate of the K/V
+(and scale) index maps, so no call slices a layer out of the stack.
 """
 
 from __future__ import annotations
@@ -72,8 +78,14 @@ def pick_tiles(s_q: int, s_kv: int, q_tile: int, kv_tile: int) -> tuple[int, int
     return pick_pattern_tiles(s_q, s_kv, q_tile, kv_tile)
 
 
+def _layer_operand(layer) -> jax.Array:
+    """The (1,) int32 scalar-prefetch operand naming the stacked pool's
+    layer a kernel reads."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
 def _prefill_kernel(
-    kvi_ref, lv_ref, vt_ref, q_ref, k_ref, v_ref, *refs,
+    ly_ref, kvi_ref, lv_ref, vt_ref, q_ref, k_ref, v_ref, *refs,
     scale: float, causal: bool, window: int | None, s_q: int, s_kv: int,
     q_tile: int, kv_tile: int, quantized: bool = False,
 ):
@@ -153,6 +165,7 @@ def mha_prefill(
     q: jax.Array,
     k: jax.Array,
     v: jax.Array,
+    layer: jax.Array,
     kv_index: jax.Array,
     step_live: jax.Array,
     *,
@@ -168,7 +181,10 @@ def mha_prefill(
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
 ) -> jax.Array:
-    """q: (BK, G, Sq_pad, D) -> y same shape; k, v: (BK, Skv_pad, D).
+    """q: (BK, G, Sq_pad, D) -> y same shape; k, v: (L, BK, Skv_pad, D), a
+    stack of which the kernel reads entry ``layer`` ((1,) int32, scalar
+    prefetch) in place.  A single cache is passed as ``k[None]`` with layer
+    0, a free reshape.
 
     ``kv_index`` / ``step_live``: (Sq_pad/q_tile, max_live) packed live
     kv-tile map (:class:`repro.core.sparsity.BlockMap`) — the kv grid axis
@@ -183,17 +199,18 @@ def mha_prefill(
     (:func:`repro.core.sparsity.translate_tables`).  Defaults to
     ``kv_index`` — the contiguous identity mapping.
 
-    ``k_scale`` / ``v_scale`` ((Skv_pad / kv_tile, BK, 1, kv_tile) float32,
-    or None): per-row dequant scales of a QUANTIZED pool, one (1, kv_tile)
-    lane row per (tile, BK row) so every block's last two dims equal the
-    array's (:func:`repro.kernels.ops._scale_layout`).  The K scales multiply
-    the score columns and the V scales the probabilities
+    ``k_scale`` / ``v_scale`` ((L, BK, Skv_pad / kv_tile, 1, kv_tile)
+    float32, or None): per-row dequant scales of a QUANTIZED pool, stored
+    stacked like k/v, one (1, kv_tile) lane row per (layer, BK row, tile) so
+    every block's last two dims equal the array's, as the TPU tiling
+    requires (:func:`repro.models.transformer.paged_pool_specs`).  The K
+    scales multiply the score columns and the V scales the probabilities
     (:mod:`repro.core.quant`); when None the call compiles the exact
     unquantized graph."""
     from jax.experimental.pallas import tpu as pltpu
 
     bk, g, sq_pad, d = q.shape
-    skv_pad = k.shape[1]
+    skv_pad = k.shape[2]
     if sq_pad % q_tile or skv_pad % kv_tile:
         raise ValueError(f"padded seqs {(sq_pad, skv_pad)} vs tiles {(q_tile, kv_tile)}")
     nq, max_live = kv_index.shape
@@ -204,27 +221,34 @@ def mha_prefill(
     quantized = k_scale is not None
 
     grid = (bk, g, nq, max_live)
+    # the leading (layer) block dim is squeezed: the kernel sees the same
+    # (1, kv_tile, d) tile as from an unstacked cache
+    kv_spec = pl.BlockSpec(
+        (None, 1, kv_tile, d),
+        lambda b, g, i, jj, ly, kvi, lv, vt: (ly[0], b, kvi[i, jj], 0),
+    )
     in_specs = [
-        pl.BlockSpec((1, 1, q_tile, d), lambda b, g, i, jj, kvi, lv, vt: (b, g, i, 0)),
-        pl.BlockSpec((1, kv_tile, d), lambda b, g, i, jj, kvi, lv, vt: (b, kvi[i, jj], 0)),
-        pl.BlockSpec((1, kv_tile, d), lambda b, g, i, jj, kvi, lv, vt: (b, kvi[i, jj], 0)),
+        pl.BlockSpec((1, 1, q_tile, d), lambda b, g, i, jj, ly, kvi, lv, vt: (b, g, i, 0)),
+        kv_spec,
+        kv_spec,
     ]
     args = [
-        kv_index.astype(jnp.int32), step_live.astype(jnp.int32),
-        kv_virt.astype(jnp.int32), q, k, v,
+        _layer_operand(layer), kv_index.astype(jnp.int32),
+        step_live.astype(jnp.int32), kv_virt.astype(jnp.int32), q, k, v,
     ]
     if quantized:
         sspec = pl.BlockSpec(
-            (1, 1, 1, kv_tile), lambda b, g, i, jj, kvi, lv, vt: (kvi[i, jj], b, 0, 0)
+            (None, 1, 1, 1, kv_tile),
+            lambda b, g, i, jj, ly, kvi, lv, vt: (ly[0], b, kvi[i, jj], 0, 0),
         )
         in_specs += [sspec, sspec]
         args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # kv_index, step_live, kv_virt drive the DMA
+        num_scalar_prefetch=4,  # layer, kv_index, step_live, kv_virt drive the DMA
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, 1, q_tile, d), lambda b, g, i, jj, kvi, lv, vt: (b, g, i, 0)
+            (1, 1, q_tile, d), lambda b, g, i, jj, ly, kvi, lv, vt: (b, g, i, 0)
         ),
         scratch_shapes=[
             pltpu.VMEM((q_tile, _LANES), jnp.float32),
@@ -495,7 +519,7 @@ def mha_decode(
 
 
 def _decode_kernel_paged(
-    cl_ref, kvi_ref, vt_ref, lv_ref, q_ref, k_ref, v_ref, *refs,
+    ly_ref, cl_ref, kvi_ref, vt_ref, lv_ref, q_ref, k_ref, v_ref, *refs,
     scale: float, window: int | None, kv_tile: int, quantized: bool = False,
 ):
     if quantized:
@@ -560,6 +584,7 @@ def mha_decode_paged(
     q: jax.Array,
     k: jax.Array,
     v: jax.Array,
+    layer: jax.Array,
     cur_len: jax.Array,
     kv_index: jax.Array,
     kv_virt: jax.Array,
@@ -573,20 +598,22 @@ def mha_decode_paged(
     v_scale: jax.Array | None = None,
 ) -> jax.Array:
     """Flash-decode over a PAGED cache: q (B, KV, Gp, D); k, v are the global
-    page pool laid out (KV, n_pages * kv_tile, D) — no batch axis, every row
-    reads the pool through its own table.  ``kv_index`` (B, max_live) holds
+    page pool as stored, stacked by layer, (L, KV, n_pages * kv_tile, D) —
+    no batch axis, every row reads the pool through its own table, and the
+    DMA reads layer ``layer`` ((1,) int32, scalar prefetch) where it lives.
+    ``kv_index`` (B, max_live) holds
     physical page ids (the DMA target), ``kv_virt`` the matching virtual kv
     tiles (the fine mask's position base), ``step_live`` the packed liveness
     (:func:`repro.core.sparsity.translate_tables`).  ``cur_len`` (B,) is each
     row's live length in virtual token space; the grid never visits a dead or
-    unallocated tile.  ``k_scale`` / ``v_scale`` ((n_pages, KV, 1, kv_tile)
-    float32, or None) carry a quantized pool's per-row dequant scales through
-    the SAME page indirection (layout as in :func:`mha_prefill`).
-    Returns (B, KV, Gp, D)."""
+    unallocated tile.  ``k_scale`` / ``v_scale`` ((L, KV, n_pages, 1,
+    kv_tile) float32, or None) carry a quantized pool's per-row dequant
+    scales through the SAME layer and page indirection (layout as in
+    :func:`mha_prefill`).  Returns (B, KV, Gp, D)."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, kvh, gp, d = q.shape
-    pool_rows = k.shape[1]
+    pool_rows = k.shape[2]
     if pool_rows % kv_tile:
         raise ValueError(f"pool rows {pool_rows} vs kv tile {kv_tile}")
     if kv_index.shape[0] != b or kv_virt.shape != kv_index.shape:
@@ -597,27 +624,33 @@ def mha_decode_paged(
     quantized = k_scale is not None
 
     grid = (b, kvh, max_live)
+    kv_spec = pl.BlockSpec(
+        (None, 1, kv_tile, d),
+        lambda b, h, jj, ly, cl, kvi, vt, lv: (ly[0], h, kvi[b, jj], 0),
+    )
     in_specs = [
-        pl.BlockSpec((1, 1, gp, d), lambda b, h, jj, cl, kvi, vt, lv: (b, h, 0, 0)),
-        pl.BlockSpec((1, kv_tile, d), lambda b, h, jj, cl, kvi, vt, lv: (h, kvi[b, jj], 0)),
-        pl.BlockSpec((1, kv_tile, d), lambda b, h, jj, cl, kvi, vt, lv: (h, kvi[b, jj], 0)),
+        pl.BlockSpec((1, 1, gp, d), lambda b, h, jj, ly, cl, kvi, vt, lv: (b, h, 0, 0)),
+        kv_spec,
+        kv_spec,
     ]
     args = [
-        cur_len.astype(jnp.int32), kv_index.astype(jnp.int32),
-        kv_virt.astype(jnp.int32), step_live.astype(jnp.int32), q, k, v,
+        _layer_operand(layer), cur_len.astype(jnp.int32),
+        kv_index.astype(jnp.int32), kv_virt.astype(jnp.int32),
+        step_live.astype(jnp.int32), q, k, v,
     ]
     if quantized:
         sspec = pl.BlockSpec(
-            (1, 1, 1, kv_tile), lambda b, h, jj, cl, kvi, vt, lv: (kvi[b, jj], h, 0, 0)
+            (None, 1, 1, 1, kv_tile),
+            lambda b, h, jj, ly, cl, kvi, vt, lv: (ly[0], h, kvi[b, jj], 0, 0),
         )
         in_specs += [sspec, sspec]
         args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,  # cur_len, kv_index, kv_virt, step_live
+        num_scalar_prefetch=5,  # layer, cur_len, kv_index, kv_virt, step_live
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, 1, gp, d), lambda b, h, jj, cl, kvi, vt, lv: (b, h, 0, 0)
+            (1, 1, gp, d), lambda b, h, jj, ly, cl, kvi, vt, lv: (b, h, 0, 0)
         ),
         scratch_shapes=[
             pltpu.VMEM((gp, _LANES), jnp.float32),
@@ -637,7 +670,7 @@ def mha_decode_paged(
 
 
 def _chunk_kernel_paged(
-    start_ref, kvi_ref, vt_ref, lv_ref, q_ref, k_ref, v_ref, *refs,
+    ly_ref, start_ref, kvi_ref, vt_ref, lv_ref, q_ref, k_ref, v_ref, *refs,
     scale: float, window: int | None, s_kv: int,
     q_tile: int, kv_tile: int, n_kv_tiles: int, pattern: str,
     pattern_arg: int | None, quantized: bool = False,
@@ -713,6 +746,7 @@ def mha_chunk_paged(
     q: jax.Array,
     k: jax.Array,
     v: jax.Array,
+    layer: jax.Array,
     start: jax.Array,
     kv_index: jax.Array,
     kv_virt: jax.Array,
@@ -731,21 +765,24 @@ def mha_chunk_paged(
 ) -> jax.Array:
     """Mixed chunked-prefill attention over a PAGED shared KV cache.
 
-    q: (B, KV, G, C_pad, D); k, v: the global page pool (KV, n_pages *
-    kv_tile, D).  ``kv_index`` (B, max_live) physical page ids, ``kv_virt``
+    q: (B, KV, G, C_pad, D); k, v: the global page pool as stored, stacked
+    by layer, (L, KV, n_pages * kv_tile, D), read at layer ``layer`` ((1,)
+    int32, scalar prefetch) in place.  ``kv_index`` (B, max_live) physical
+    page ids, ``kv_virt``
     the matching virtual kv tiles, ``step_live`` packed liveness — the
     translated form of :func:`repro.core.sparsity.chunk_live_tables`.
     ``s_kv`` is the VIRTUAL cache length (fine masks index virtual token
     positions; the per-query pattern gate runs on virtual tiles).  Same grid
     semantics as :func:`mha_chunk` with the batch and kv-head axes split so
-    the pool needs no per-row copy.  ``k_scale`` / ``v_scale`` ((n_pages,
-    KV, 1, kv_tile) float32, or None): quantized-pool per-row dequant
-    scales, page-indirected like K/V (layout as in :func:`mha_prefill`).
+    the pool needs no per-row copy.  ``k_scale`` / ``v_scale`` ((L, KV,
+    n_pages, 1, kv_tile) float32, or None): quantized-pool per-row dequant
+    scales, layer- and page-indirected like K/V (layout as in
+    :func:`mha_prefill`).
     Returns (B, KV, G, C_pad, D)."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, kvh, g, cp, d = q.shape
-    pool_rows = k.shape[1]
+    pool_rows = k.shape[2]
     if pool_rows % kv_tile:
         raise ValueError(f"pool rows {pool_rows} vs kv tile {kv_tile}")
     if kv_index.shape[0] != b or start.shape[0] != b:
@@ -756,37 +793,37 @@ def mha_chunk_paged(
     quantized = k_scale is not None
 
     grid = (b, kvh, g, max_live)
+    kv_spec = pl.BlockSpec(
+        (None, 1, kv_tile, d),
+        lambda b, h, gg, jj, ly, st, kvi, vt, lv: (ly[0], h, kvi[b, jj], 0),
+    )
     in_specs = [
         pl.BlockSpec(
             (1, 1, 1, cp, d),
-            lambda b, h, gg, jj, st, kvi, vt, lv: (b, h, gg, 0, 0),
+            lambda b, h, gg, jj, ly, st, kvi, vt, lv: (b, h, gg, 0, 0),
         ),
-        pl.BlockSpec(
-            (1, kv_tile, d),
-            lambda b, h, gg, jj, st, kvi, vt, lv: (h, kvi[b, jj], 0),
-        ),
-        pl.BlockSpec(
-            (1, kv_tile, d),
-            lambda b, h, gg, jj, st, kvi, vt, lv: (h, kvi[b, jj], 0),
-        ),
+        kv_spec,
+        kv_spec,
     ]
     args = [
-        start.astype(jnp.int32), kv_index.astype(jnp.int32),
-        kv_virt.astype(jnp.int32), step_live.astype(jnp.int32), q, k, v,
+        _layer_operand(layer), start.astype(jnp.int32),
+        kv_index.astype(jnp.int32), kv_virt.astype(jnp.int32),
+        step_live.astype(jnp.int32), q, k, v,
     ]
     if quantized:
         sspec = pl.BlockSpec(
-            (1, 1, 1, kv_tile),
-            lambda b, h, gg, jj, st, kvi, vt, lv: (kvi[b, jj], h, 0, 0),
+            (None, 1, 1, 1, kv_tile),
+            lambda b, h, gg, jj, ly, st, kvi, vt, lv: (ly[0], h, kvi[b, jj], 0, 0),
         )
         in_specs += [sspec, sspec]
         args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,  # start, kv_index, kv_virt, step_live
+        num_scalar_prefetch=5,  # layer, start, kv_index, kv_virt, step_live
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, 1, 1, cp, d), lambda b, h, gg, jj, st, kvi, vt, lv: (b, h, gg, 0, 0)
+            (1, 1, 1, cp, d),
+            lambda b, h, gg, jj, ly, st, kvi, vt, lv: (b, h, gg, 0, 0),
         ),
         scratch_shapes=[
             pltpu.VMEM((cp, _LANES), jnp.float32),

@@ -183,7 +183,8 @@ def _flash_prefill_raw(
     vt = jnp.pad(vt, ((0, 0), (0, skv_pad - skv), (0, d - hd)))
 
     y = fa.mha_prefill(
-        qt, kt, vt, jnp.asarray(bm.kv_index), jnp.asarray(bm.step_live),
+        qt, kt[None], vt[None], 0, jnp.asarray(bm.kv_index),
+        jnp.asarray(bm.step_live),
         scale=1.0 / math.sqrt(hd), causal=causal, window=window,
         s_q=s, s_kv=skv, q_tile=tq, kv_tile=tk, interpret=_interpret(),
     )
@@ -398,35 +399,32 @@ def flash_decode(
 # --------------------------------------------------------------------------
 
 
-def _pool_layout(k_pool: jax.Array, v_pool: jax.Array, page: int):
-    """(P*page, KV, hd) pool -> kernel layout (KV, P*page, D_pad) + counts."""
-    rows, kvh, hd = k_pool.shape
+def _pool_dims(k_pool: jax.Array, page: int) -> tuple[int, int]:
+    """(KV heads, pages) of a stacked pool leaf (L, KV, n_pages * page, hd),
+    the layout the kernels read where it is stored.  The pool keeps the
+    model's head width: the kernels' K/V block spans it whole (a block dim
+    equal to the array's is legal at any width), so neither the pool nor the
+    queries are padded."""
+    _, kvh, rows, _ = k_pool.shape
     if rows % page:
         raise ValueError(f"pool rows {rows} not a page multiple ({page})")
-    d = _round_up(hd, _LANES)
-    kt = jnp.swapaxes(k_pool, 0, 1)
-    vt = jnp.swapaxes(v_pool, 0, 1)
-    kt = jnp.pad(kt, ((0, 0), (0, 0), (0, d - hd)))
-    vt = jnp.pad(vt, ((0, 0), (0, 0), (0, d - hd)))
-    return kt, vt, rows // page, d
+    return kvh, rows // page
 
 
-def _scale_layout(k_scale, v_scale, page: int):
-    """(P*page, KV) per-row dequant scales -> kernel layout (P, KV, 1, page)
-    f32 — the scale analogue of :func:`_pool_layout`.  A page's scales for
-    one kv head are one lane row, and the kernels' (1, 1, 1, page) block then
-    has its last two dims equal to the array's, as the TPU tiling requires
-    (a (1, page) block over (KV, P*page) has a second-minor 1 that is
-    neither a multiple of 8 nor the full KV extent)."""
-    if k_scale is None:
-        return None, None
-
-    def lay(s):
-        rows, kvh = s.shape
-        s = s.astype(jnp.float32).reshape(rows // page, page, kvh)
-        return jnp.swapaxes(s, 1, 2)[:, :, None, :]
-
-    return lay(k_scale), lay(v_scale)
+def _kernel_pools(layer, *leaves):
+    """``(layer, leaves)`` as a paged kernel takes them.  Compiled for the
+    TPU the kernel reads the stacked leaves where they are stored.  In
+    interpret mode (the CPU) the grid runs as a while loop that carries
+    every operand and writes its blocks back, so a stacked leaf passed whole
+    would be copied on every call: it is handed the one layer it reads, as
+    a one-layer stack read at layer 0."""
+    if not _interpret():
+        return layer, leaves
+    layer = jnp.asarray(layer, jnp.int32)
+    return 0, tuple(
+        None if a is None else jax.lax.dynamic_slice_in_dim(a, layer, 1, 0)
+        for a in leaves
+    )
 
 
 def _virtual_extent(page_table: jax.Array, page: int, kv_live: int | None) -> int:
@@ -460,6 +458,7 @@ def flash_paged_prefill(
     page_table: jax.Array,
     *,
     page: int,
+    layer: jax.Array | int = 0,
     spec: AttentionSpec | None = None,
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
@@ -467,15 +466,18 @@ def flash_paged_prefill(
     """Fused prefill attention reading prompt KV back through the page pool.
 
     q: (1, S, H, hd) — one admitted request's (bucketed) prompt, positions
-    0..S-1; ``k_pool`` / ``v_pool``: (n_pages * page, KV, hd) the global
-    pool, already holding this prompt's KV (the model layer scatters before
-    attention); ``page_table``: (n_vtiles,) this request's virtual-tile ->
+    0..S-1; ``k_pool`` / ``v_pool``: (L, KV, n_pages * page, hd) the global
+    pool as stored, stacked by layer, already holding this prompt's KV in
+    layer ``layer`` (the model layer scatters before attention), which the
+    kernel reads in place — a single-layer pool is passed as ``pool[None]``
+    with layer 0; ``page_table``: (n_vtiles,) this request's virtual-tile ->
     physical-page map.  The static block map over the prompt translates to
     physical page ids, so the prefill grid streams pool pages directly —
     batch-1 because the table is shared across grid rows, which is exactly
-    the admission engine's shape.  ``k_scale`` / ``v_scale`` ((n_pages *
-    page, KV) f32 or None) are a quantized pool's per-row dequant scales —
-    forwarded through the same page indirection."""
+    the admission engine's shape.  ``k_scale`` / ``v_scale`` ((L, KV,
+    n_pages, 1, page) f32 or None) are a quantized pool's per-row dequant
+    scales, stored beside it — forwarded through the same layer and page
+    indirection."""
     spec = spec or AttentionSpec(impl="flash_kernel")
     pattern, arg, causal, window = canonical_pattern(
         spec.pattern, spec.pattern_arg, True, None
@@ -485,9 +487,8 @@ def flash_paged_prefill(
         raise ValueError(
             f"paged prefill is batch-1 (shared block map), got batch {b}"
         )
-    kvh = k_pool.shape[1]
+    kvh, n_pages = _pool_dims(k_pool, page)
     g = h // kvh
-    kt, vt, n_pages, d = _pool_layout(k_pool, v_pool, page)
     tq, _ = fa.pick_tiles(s, s, spec.q_tile, spec.kv_tile)
     sq_pad = _round_up(s, tq)
 
@@ -500,16 +501,18 @@ def flash_paged_prefill(
     )
 
     qt = q.reshape(1, s, kvh, g, hd).transpose(0, 2, 3, 1, 4).reshape(kvh, g, s, hd)
-    qt = jnp.pad(qt, ((0, 0), (0, 0), (0, sq_pad - s), (0, d - hd)))
+    qt = jnp.pad(qt, ((0, 0), (0, 0), (0, sq_pad - s), (0, 0)))
 
-    ks, vs = _scale_layout(k_scale, v_scale, page)
+    layer, (k_pool, v_pool, k_scale, v_scale) = _kernel_pools(
+        layer, k_pool, v_pool, k_scale, v_scale
+    )
     y = fa.mha_prefill(
-        qt, kt, vt, kv_phys, step_live,
+        qt, k_pool, v_pool, layer, kv_phys, step_live,
         scale=1.0 / math.sqrt(hd), causal=causal, window=window,
         s_q=s, s_kv=s, q_tile=tq, kv_tile=page, interpret=_interpret(),
-        kv_virt=kv_virt, k_scale=ks, v_scale=vs,
+        kv_virt=kv_virt, k_scale=k_scale, v_scale=v_scale,
     )
-    y = y[:, :, :s, :hd].reshape(1, kvh, g, s, hd)
+    y = y[:, :, :s].reshape(1, kvh, g, s, hd)
     return y.transpose(0, 3, 1, 2, 4).reshape(1, s, h, hd)
 
 
@@ -522,6 +525,7 @@ def flash_paged_chunk(
     page_table: jax.Array,
     *,
     page: int,
+    layer: jax.Array | int = 0,
     spec: AttentionSpec | None = None,
     kv_live: int | None = None,
     ring_window: int | None = None,
@@ -530,9 +534,12 @@ def flash_paged_chunk(
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
 ) -> jax.Array:
-    """Paged form of :func:`flash_chunk`: q (B, C, H, hd) mixed rows over the
-    shared pool (n_pages * page, KV, hd), each row reading through its own
-    ``page_table`` row (B, n_vtiles).  The per-row chunk tables are built in
+    """Paged form of :func:`flash_chunk`: q (B, C, H, hd) mixed rows over
+    layer ``layer`` of the shared pool, stored stacked by layer (L, KV,
+    n_pages * page, hd) and read in place, each row reading through its own
+    ``page_table`` row (B, n_vtiles).  ``k_scale`` / ``v_scale`` are a
+    quantized pool's (L, KV, n_pages, 1, page) scales (as in
+    :func:`flash_paged_prefill`).  The per-row chunk tables are built in
     VIRTUAL tile space (identical liveness to the contiguous engine) and
     translated to physical pages — the kernel grid never visits a dead or
     unallocated tile, and ``kv_live`` buckets the virtual extent exactly as
@@ -556,9 +563,8 @@ def flash_paged_chunk(
         spec.pattern, spec.pattern_arg, True, None
     )
     b, c, h, hd = q.shape
-    kvh = k_pool.shape[1]
+    kvh, n_pages = _pool_dims(k_pool, page)
     g = h // kvh
-    kt, vt, n_pages, d = _pool_layout(k_pool, v_pool, page)
     cp = _round_up(c, 8)
 
     start = jnp.asarray(start, jnp.int32).reshape(-1)
@@ -585,16 +591,18 @@ def flash_paged_chunk(
     )
 
     qt = q.reshape(b, c, kvh, g, hd).transpose(0, 2, 3, 1, 4)
-    qt = jnp.pad(qt, ((0, 0), (0, 0), (0, 0), (0, cp - c), (0, d - hd)))
+    qt = jnp.pad(qt, ((0, 0), (0, 0), (0, 0), (0, cp - c), (0, 0)))
 
-    ks, vs = _scale_layout(k_scale, v_scale, page)
+    layer, (k_pool, v_pool, k_scale, v_scale) = _kernel_pools(
+        layer, k_pool, v_pool, k_scale, v_scale
+    )
     y = fa.mha_chunk_paged(
-        qt, kt, vt, start, kv_phys, kv_virt, step_live,
+        qt, k_pool, v_pool, layer, start, kv_phys, kv_virt, step_live,
         scale=1.0 / math.sqrt(hd), window=window, s_kv=skv,
         q_tile=spec.q_tile, kv_tile=page, pattern=pattern, pattern_arg=arg,
-        interpret=_interpret(), k_scale=ks, v_scale=vs,
+        interpret=_interpret(), k_scale=k_scale, v_scale=v_scale,
     )
-    y = y[:, :, :, :c, :hd]
+    y = y[:, :, :, :c]
     return y.transpose(0, 3, 1, 2, 4).reshape(b, c, h, hd)
 
 
@@ -606,6 +614,7 @@ def flash_paged_decode(
     page_table: jax.Array,
     *,
     page: int,
+    layer: jax.Array | int = 0,
     spec: AttentionSpec | None = None,
     kv_live: int | None = None,
     ring_window: int | None = None,
@@ -614,7 +623,9 @@ def flash_paged_decode(
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
 ) -> jax.Array:
-    """Paged form of :func:`flash_decode`: q (B, H, hd) over the shared pool.
+    """Paged form of :func:`flash_decode`: q (B, H, hd) over layer ``layer``
+    of the shared pool, stored stacked by layer (L, KV, n_pages * page, hd)
+    and read in place (scales as in :func:`flash_paged_prefill`).
 
     Each row's per-position live-tile table (the same
     :func:`repro.core.sparsity.decode_live_tables` the contiguous kernel
@@ -635,9 +646,8 @@ def flash_paged_decode(
         spec.pattern, spec.pattern_arg, True, None
     )
     b, h, hd = q.shape
-    kvh = k_pool.shape[1]
+    kvh, n_pages = _pool_dims(k_pool, page)
     g = h // kvh
-    kt, vt, n_pages, d = _pool_layout(k_pool, v_pool, page)
     gp = _round_up(g, 8)
 
     cl_rows = jnp.broadcast_to(jnp.asarray(cur_len, jnp.int32).reshape(-1), (b,))
@@ -658,15 +668,17 @@ def flash_paged_decode(
         page_range=page_range,
     )
 
-    qt = jnp.pad(q.reshape(b, kvh, g, hd), ((0, 0), (0, 0), (0, gp - g), (0, d - hd)))
+    qt = jnp.pad(q.reshape(b, kvh, g, hd), ((0, 0), (0, 0), (0, gp - g), (0, 0)))
 
-    ks, vs = _scale_layout(k_scale, v_scale, page)
-    y = fa.mha_decode_paged(
-        qt, kt, vt, cl_rows, kv_phys, kv_virt, step_live,
-        scale=1.0 / math.sqrt(hd), window=window, kv_tile=page,
-        interpret=_interpret(), k_scale=ks, v_scale=vs,
+    layer, (k_pool, v_pool, k_scale, v_scale) = _kernel_pools(
+        layer, k_pool, v_pool, k_scale, v_scale
     )
-    return y[:, :, :g, :hd].reshape(b, h, hd)
+    y = fa.mha_decode_paged(
+        qt, k_pool, v_pool, layer, cl_rows, kv_phys, kv_virt, step_live,
+        scale=1.0 / math.sqrt(hd), window=window, kv_tile=page,
+        interpret=_interpret(), k_scale=k_scale, v_scale=v_scale,
+    )
+    return y[:, :, :g].reshape(b, h, hd)
 
 
 def fnet_mixing_kernel(x: jax.Array, max_radix: int = sd.MAX_RADIX_COMPLEX) -> jax.Array:

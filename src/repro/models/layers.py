@@ -396,11 +396,15 @@ def run_decode_attention(
 def gather_pages(
     pool: jax.Array, page_table: jax.Array, n_rows: int, page: int,
     page_range: tuple[int, int] | None = None,
+    layer: jax.Array | int = 0,
 ) -> jax.Array:
     """Materialise rows ``0..n_rows-1`` of each request's VIRTUAL cache from
-    the shared page pool.  pool: (n_pages * page, KV, hd); page_table:
-    (B, n_vtiles) physical page ids (sentinel ``n_pages`` = unallocated) ->
-    (B, n_rows, KV, hd).  Unallocated tiles gather clamped garbage — every
+    layer ``layer`` of the shared page pool, read where it is stored: only
+    the gathered rows move, no layer is sliced out first.  pool: a stacked
+    pool leaf, (L, KV, n_pages * page, hd) K/V -> (B, n_rows, KV, hd), or a
+    quantized pool's scale leaf (L, KV, n_pages, 1, page) -> (B, n_rows, KV);
+    page_table: (B, n_vtiles) physical page ids (sentinel ``n_pages`` =
+    unallocated).  Unallocated tiles gather clamped garbage — every
     consumer masks them (causal frontier / cur_len / pattern), exactly as the
     contiguous engine masks its unwritten rows.
 
@@ -417,25 +421,31 @@ def gather_pages(
     exactly one shard, so a sum over the shards' gathers reassembles the
     replicated gather on every allocated row (a ``psum`` inside
     ``shard_map``, a plain sum in the host-side sweep test)."""
+    scale_leaf = pool.ndim == 5
+    n_pages = pool.shape[2] if scale_leaf else pool.shape[2] // page
+    rows = jnp.arange(n_rows, dtype=jnp.int32)
+    off = rows % page
+    phys = page_table[:, rows // page]  # (B, n_rows) global ids
     if page_range is not None:
         lo, hi = page_range
-        rows = jnp.arange(n_rows, dtype=jnp.int32)
-        vt = rows // page
-        phys = page_table[:, vt]  # (B, n_rows) global ids
         owned = (phys >= lo) & (phys < hi)
-        loc = jnp.clip(phys - lo, 0, hi - lo - 1)
-        flat = loc * page + (rows % page)[None, :]
-        out = pool[flat]
-        # broadcast `owned` over the pool's trailing dims — (KV, hd) for a
-        # KV pool, (KV,) for a quantized pool's per-row scale leaf
-        owned = owned.reshape(owned.shape + (1,) * (out.ndim - 2))
-        return jnp.where(owned, out, jnp.zeros((), out.dtype))
-    n_pages = pool.shape[0] // page
-    rows = jnp.arange(n_rows, dtype=jnp.int32)
-    vt = rows // page  # (n_rows,)
-    phys = jnp.clip(page_table[:, vt], 0, n_pages - 1)  # (B, n_rows)
-    flat = phys * page + (rows % page)[None, :]
-    return pool[flat]
+        phys = jnp.clip(phys - lo, 0, hi - lo - 1)
+    else:
+        phys = jnp.clip(phys, 0, n_pages - 1)
+    # one index per (row, kv head), each reading one row of hd (one lane row
+    # of scales), as the write scatters them: no backend has to move the
+    # pool's axes to gather from it
+    heads = jnp.arange(pool.shape[1], dtype=jnp.int32)
+    if scale_leaf:
+        out = pool[layer, heads, phys[..., None], 0, off[..., None]]
+    else:
+        out = pool[layer, heads, (phys * page + off)[..., None]]
+    if page_range is None:
+        return out
+    # broadcast `owned` over the trailing dims — (KV, hd) for a K/V leaf,
+    # (KV,) for a scale leaf
+    owned = owned.reshape(owned.shape + (1,) * (out.ndim - 2))
+    return jnp.where(owned, out, jnp.zeros((), out.dtype))
 
 
 def ring_kpos(frontier: jax.Array, page: int, ring_tiles: int) -> jax.Array:
@@ -453,17 +463,19 @@ def ring_kpos(frontier: jax.Array, page: int, ring_tiles: int) -> jax.Array:
     return (base[:, :, None] + off[None, None, :]).reshape(st.shape[0], -1)
 
 
-def _gather_dequant(q, k_pool, v_pool, k_scale, v_scale, page_table, n_rows, page):
-    """Gather both pools' virtual rows and, for a quantized pool, the
-    matching scale rows — reconstructing the bf16 cache the contiguous
-    (oracle) forms consume.  The scale leaves ride the SAME page table, so a
-    CoW-forked, radix-aliased, or ring-phased page always lands next to its
-    own scales."""
-    kg = gather_pages(k_pool, page_table, n_rows, page)
-    vg = gather_pages(v_pool, page_table, n_rows, page)
+def _gather_dequant(
+    q, k_pool, v_pool, k_scale, v_scale, page_table, n_rows, page, layer
+):
+    """Gather both pools' virtual rows of layer ``layer`` and, for a
+    quantized pool, the matching scale rows — reconstructing the bf16 cache
+    the contiguous (oracle) forms consume.  The scale leaves ride the SAME
+    page table, so a CoW-forked, radix-aliased, or ring-phased page always
+    lands next to its own scales."""
+    kg = gather_pages(k_pool, page_table, n_rows, page, layer=layer)
+    vg = gather_pages(v_pool, page_table, n_rows, page, layer=layer)
     if k_scale is not None:
-        ks = gather_pages(k_scale, page_table, n_rows, page)
-        vs = gather_pages(v_scale, page_table, n_rows, page)
+        ks = gather_pages(k_scale, page_table, n_rows, page, layer=layer)
+        vs = gather_pages(v_scale, page_table, n_rows, page, layer=layer)
         kg = quant.dequantize_rows(kg, ks, dtype=q.dtype)
         vg = quant.dequantize_rows(vg, vs, dtype=q.dtype)
     return kg, vg
@@ -478,13 +490,15 @@ def run_paged_prefill_attention(
     page_table: jax.Array,
     *,
     page: int,
+    layer: jax.Array | int = 0,
     spec: AttentionSpec = AttentionSpec(),
     rt: Runtime = Runtime(),
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
 ) -> jax.Array:
     """Admission prefill over a paged cache: q/k_new/v_new are the (1, S)
-    prompt's projections (already scattered into the pool by the caller).
+    prompt's projections (already scattered into layer ``layer`` of the
+    stacked pools (L, KV, n_pages * page, hd) by the caller).
     The fused kernel reads the KV back *through the page table* — the
     physical-page indexing proof for the prefill grid; the XLA form attends
     the in-flight projections directly (the gather would reproduce them, and
@@ -494,7 +508,7 @@ def run_paged_prefill_attention(
         from repro.kernels import ops
 
         return ops.flash_paged_prefill(
-            q, k_pool, v_pool, page_table, page=page, spec=spec,
+            q, k_pool, v_pool, page_table, page=page, layer=layer, spec=spec,
             k_scale=k_scale, v_scale=v_scale,
         )
     return run_attention(q, k_new, v_new, spec=spec, causal=True, rt=rt)
@@ -508,6 +522,7 @@ def run_paged_decode_attention(
     page_table: jax.Array,
     *,
     page: int,
+    layer: jax.Array | int = 0,
     spec: AttentionSpec = AttentionSpec(),
     rt: Runtime = Runtime(),
     kv_live: int | None = None,
@@ -516,9 +531,11 @@ def run_paged_decode_attention(
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
 ) -> jax.Array:
-    """One-token attention over the paged pool: q (B, H, hd), per-row
-    ``cur_len`` live lengths in virtual token space.  ``kv_live`` buckets the
-    virtual extent (compile-per-bucket, like the contiguous engine).
+    """One-token attention over layer ``layer`` of the stacked paged pools
+    (L, KV, n_pages * page, hd), read where they are stored: q (B, H, hd),
+    per-row ``cur_len`` live lengths in virtual token space.  ``kv_live``
+    buckets the virtual extent (compile-per-bucket, like the contiguous
+    engine).
     ``ring_window`` / ``ring_tiles`` select the mod-window ring form:
     positions are unbounded, the table's ``ring_tiles`` slots are reused in
     phase, and only the trailing ``ring_window`` keys are live.
@@ -529,7 +546,8 @@ def run_paged_decode_attention(
         from repro.kernels import ops
 
         return ops.flash_paged_decode(
-            q, k_pool, v_pool, cur_len, page_table, page=page, spec=spec,
+            q, k_pool, v_pool, cur_len, page_table, page=page, layer=layer,
+            spec=spec,
             kv_live=kv_live, ring_window=ring_window, ring_tiles=ring_tiles,
             k_scale=k_scale, v_scale=v_scale,
         )
@@ -539,7 +557,7 @@ def run_paged_decode_attention(
         )
         kg, vg = _gather_dequant(
             q, k_pool, v_pool, k_scale, v_scale, page_table,
-            ring_tiles * page, page,
+            ring_tiles * page, page, layer,
         )
         kpos = ring_kpos(cl - 1, page, ring_tiles)  # (B, R*page) slot order
         mask = (kpos < cl[:, None]) & (kpos > (cl[:, None] - 1 - ring_window))
@@ -548,7 +566,7 @@ def run_paged_decode_attention(
     if kv_live is not None:
         n_rows = min(n_rows, max(int(kv_live), 1))
     kg, vg = _gather_dequant(
-        q, k_pool, v_pool, k_scale, v_scale, page_table, n_rows, page
+        q, k_pool, v_pool, k_scale, v_scale, page_table, n_rows, page, layer
     )
     return run_decode_attention(q, kg, vg, cur_len, spec=spec, rt=rt)
 
@@ -562,6 +580,7 @@ def run_paged_chunk_attention(
     page_table: jax.Array,
     *,
     page: int,
+    layer: jax.Array | int = 0,
     spec: AttentionSpec = AttentionSpec(),
     rt: Runtime = Runtime(),
     kv_live: int | None = None,
@@ -570,8 +589,9 @@ def run_paged_chunk_attention(
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
 ) -> jax.Array:
-    """Mixed chunked-prefill attention over the paged pool (the paged form of
-    :func:`run_chunk_attention`): q (B, C, H, hd) rows at absolute positions
+    """Mixed chunked-prefill attention over layer ``layer`` of the stacked
+    paged pools (the paged form of :func:`run_chunk_attention`), read where
+    they are stored: q (B, C, H, hd) rows at absolute positions
     ``start[b]..``, per-row page tables, per-row live-tile tables translated
     to physical pages.  ``ring_window`` / ``ring_tiles`` select the
     mod-window ring form (slot-phase tables, absolute-position masks).
@@ -581,7 +601,8 @@ def run_paged_chunk_attention(
         from repro.kernels import ops
 
         return ops.flash_paged_chunk(
-            q, k_pool, v_pool, start, ntok, page_table, page=page, spec=spec,
+            q, k_pool, v_pool, start, ntok, page_table, page=page, layer=layer,
+            spec=spec,
             kv_live=kv_live, ring_window=ring_window, ring_tiles=ring_tiles,
             k_scale=k_scale, v_scale=v_scale,
         )
@@ -591,7 +612,7 @@ def run_paged_chunk_attention(
         fr = sv + jnp.maximum(nv, 1) - 1  # per-row write frontier
         kg, vg = _gather_dequant(
             q, k_pool, v_pool, k_scale, v_scale, page_table,
-            ring_tiles * page, page,
+            ring_tiles * page, page, layer,
         )
         kpos = ring_kpos(fr, page, ring_tiles)
         return chunk_attention_cache(
@@ -601,7 +622,7 @@ def run_paged_chunk_attention(
     if kv_live is not None:
         n_rows = min(n_rows, max(int(kv_live), 1))
     kg, vg = _gather_dequant(
-        q, k_pool, v_pool, k_scale, v_scale, page_table, n_rows, page
+        q, k_pool, v_pool, k_scale, v_scale, page_table, n_rows, page, layer
     )
     return run_chunk_attention(q, kg, vg, start, ntok, spec=spec, rt=rt)
 

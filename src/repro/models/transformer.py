@@ -186,11 +186,14 @@ def _paged_kv_write(
     page: int,
     ring_tiles: int | None = None,
     scale: jax.Array | None = None,
-    layer: jax.Array | int | None = None,
+    layer: jax.Array | int = 0,
 ):
-    """Page-table-indirected masked scatter: token KV at absolute positions
-    ``rows`` (B, C) lands at ``page_table[b, rows // page] * page + rows %
-    page`` of the flat pool (n_pages * page, KV, hd).  Rows that are invalid
+    """Page-table-indirected masked scatter into layer ``layer`` of a
+    stacked pool leaf (L, KV, n_pages * page, hd), in place: token KV
+    ``new`` (B, C, KV, hd) at absolute positions ``rows`` (B, C) lands at
+    ``(layer, :, page_table[b, rows // page] * page + rows % page)`` — B x C
+    rows of hd per kv head, with no copy of the layer's pool (the pool
+    :func:`run_stack` carries through its layer scan).  Rows that are invalid
     (beyond ``ntok`` / ``lengths``) or whose virtual tile is unallocated
     (sentinel id) scatter out of bounds and are dropped — a row can never
     clobber a page it does not own.
@@ -209,61 +212,55 @@ def _paged_kv_write(
     entry, making the first divergent write land in a private copy.
 
     ``scale`` selects the QUANTIZED pool form: the pool stores int8 /
-    fp8_e4m3 pages and ``scale`` is the matching (n_pages * page, KV) f32
-    per-row-per-head scale pool.  Each written row is quantized
-    independently (:func:`repro.core.quant.quantize_rows` — symmetric absmax
-    over head_dim, the scheme resolved from ``pool.dtype``) and its scale
-    scatters through the SAME flat page-row index, so a page and its scales
-    can never diverge — CoW copies, radix aliasing, rings, and shard
-    transfers carry them as one unit.  Returns ``(pool, scale)`` in that
-    form, the pool alone otherwise.
-
-    ``layer`` selects the STACKED form: ``pool`` (and ``scale``) keep their
-    leading ``n_periods`` axis and the rows land at ``(layer, flat)`` — an
-    in-place scatter of B x C rows into the pool :func:`run_stack` carries
-    through its layer scan, with no copy of the layer's pool."""
-    lead = () if layer is None else (layer,)
-    n_rows = pool.shape[len(lead)]
+    fp8_e4m3 pages and ``scale`` is the matching stacked f32 scale leaf
+    (L, KV, n_pages, 1, page), one scale per (row, kv head), one lane row
+    per page.  Each written row is quantized independently
+    (:func:`repro.core.quant.quantize_rows` — symmetric absmax over
+    head_dim, the scheme resolved from ``pool.dtype``) and its scale
+    scatters to the SAME (layer, page, row), so a page and its scales can
+    never diverge — CoW copies, radix aliasing, rings, and shard transfers
+    carry them as one unit.  Returns ``(pool, scale)`` in that form, the
+    pool alone otherwise."""
+    n_pages = pool.shape[2] // page
     vt = rows // page
     if ring_tiles is not None:
         vt = vt % ring_tiles
     vt = jnp.clip(vt, 0, page_table.shape[1] - 1)
     phys = jnp.take_along_axis(page_table, vt, axis=1)
-    flat = phys * page + rows % page
-    flat = jnp.where(valid & (phys < n_rows // page), flat, n_rows).reshape(-1)
+    phys = jnp.where(valid & (phys < n_pages), phys, n_pages).reshape(-1, 1)
+    off = (rows % page).reshape(-1, 1)
+    # one index per (row, kv head), so every scattered dimension leads the
+    # operand's and each update is one row of hd: no backend has to move the
+    # pool's axes to scatter into it
+    heads = jnp.arange(pool.shape[1], dtype=jnp.int32)[None, :]
     if scale is None:
-        return pool.at[(*lead, flat)].set(
+        return pool.at[layer, heads, phys * page + off].set(
             new.astype(pool.dtype).reshape(-1, *new.shape[2:]), mode="drop"
         )
     qv, sc = quant.quantize_rows(new, pool.dtype)  # (B, C, KV, hd), (B, C, KV)
-    pool = pool.at[(*lead, flat)].set(
+    pool = pool.at[layer, heads, phys * page + off].set(
         qv.reshape(-1, *qv.shape[2:]), mode="drop"
     )
-    scale = scale.at[(*lead, flat)].set(
-        sc.reshape(-1, *sc.shape[2:]).astype(scale.dtype), mode="drop"
+    scale = scale.at[layer, heads, phys, 0, off].set(
+        sc.reshape(-1, sc.shape[2]).astype(scale.dtype), mode="drop"
     )
     return pool, scale
 
 
-def _layer_of(pool: jax.Array | None, layer: jax.Array | None):
-    """Layer ``layer`` of a stacked pool leaf (the leaf itself when
-    ``layer`` is None: the pool is already one layer's)."""
-    if pool is None or layer is None:
-        return pool
-    return jax.lax.dynamic_index_in_dim(pool, layer, axis=0, keepdims=False)
-
-
 def paged_copy_page(caches: dict, src: jax.Array, dst: jax.Array, page: int) -> dict:
     """Copy physical page ``src``'s rows onto page ``dst`` in every pool leaf
-    — the device half of a copy-on-write fork.  ``src``/``dst`` are traced
-    scalars so the host engine compiles this once; positions the copied page
-    holds that the forking request has not written yet are either identical
-    prefix KV (shared tokens) or masked by the causal frontier until the
-    request overwrites them."""
+    and every layer — the device half of a copy-on-write fork.  A K/V leaf
+    (L, KV, n_pages * page, hd) holds a page as ``page`` rows of axis 2, a
+    scale leaf (L, KV, n_pages, 1, page) as one index of axis 2.
+    ``src``/``dst`` are traced scalars so the host engine compiles this
+    once; positions the copied page holds that the forking request has not
+    written yet are either identical prefix KV (shared tokens) or masked by
+    the causal frontier until the request overwrites them."""
 
-    def cp(c):  # (n_periods, n_pages * page, KV, hd)
-        rows = jax.lax.dynamic_slice_in_dim(c, src * page, page, axis=1)
-        return jax.lax.dynamic_update_slice_in_dim(c, rows, dst * page, axis=1)
+    def cp(c):
+        n = 1 if c.ndim == 5 else page  # scale leaf: one page per index
+        rows = jax.lax.dynamic_slice_in_dim(c, src * n, n, axis=2)
+        return jax.lax.dynamic_update_slice_in_dim(c, rows, dst * n, axis=2)
 
     return jax.tree.map(cp, caches)
 
@@ -325,9 +322,9 @@ def apply_attention(
         # ``page_table`` merely aliases them — decode/chunk steps never write
         # a cross page, so copy-on-write can never trigger and every decoder
         # sharing the encoder output shares the physical pages outright.
-        assert cache is not None and page is not None
-        kg = gather_pages(_layer_of(cache["k"], layer), page_table, cfg.enc_seq, page)
-        vg = gather_pages(_layer_of(cache["v"], layer), page_table, cfg.enc_seq, page)
+        assert cache is not None and page is not None and layer is not None
+        kg = gather_pages(cache["k"], page_table, cfg.enc_seq, page, layer=layer)
+        vg = gather_pages(cache["v"], page_table, cfg.enc_seq, page, layer=layer)
         if mode == "decode":
             out = run_decode_attention(
                 q[:, 0], kg, vg, None, spec=spec, rt=rt
@@ -336,8 +333,10 @@ def apply_attention(
             out = run_attention(q, kg, vg, spec=spec, causal=False, rt=rt)
         new_cache = cache  # pools untouched by construction
     elif page_table is not None:
-        # paged KV cache: ``cache`` is the GLOBAL page pool (n_pages * page,
-        # KV, hd) shared by every batch row; ``page_table`` (B, n_vtiles)
+        # paged KV cache: ``cache`` is the GLOBAL page pool, stacked by
+        # layer (L, KV, n_pages * page, hd), shared by every batch row and
+        # read and written at layer ``layer`` where it lives (run_stack
+        # carries it through the layer scan); ``page_table`` (B, n_vtiles)
         # maps each row's virtual kv tiles to physical pages.  Writes are
         # page-table-indirected masked scatters (invalid / unallocated rows
         # drop), reads go through the translated live-tile tables — the same
@@ -346,10 +345,8 @@ def apply_attention(
         # absolute tile j lives in slot j % ring_tiles, positions are
         # unbounded, and the fine masks window on absolute positions — the
         # paged replacement for the contiguous ``_ring_place`` path.
-        # With ``layer`` the pools are the whole stack :func:`run_stack`
-        # carries: the write lands at (layer, row) in place, and attention
-        # reads layer ``layer`` of the written pools.
         assert cache is not None and pos is not None and page is not None
+        assert layer is not None, "paged pools are stacked by layer"
         ring_tiles = ring_window = None
         if cfg.sliding_window:
             _, _, _, sw = sparsity.canonical_pattern(
@@ -359,13 +356,14 @@ def apply_attention(
             ring_tiles = page_table.shape[1]
             spec = dataclasses.replace(spec, pattern="dense")
         kc, vc = cache["k"], cache["v"]
-        # quantized pools carry per-(row, kv_head) scale leaves in the same
-        # flat page layout as K/V — absent for bf16 (see repro.core.quant)
+        # quantized pools carry per-(row, kv_head) scale leaves stacked and
+        # paged like K/V — absent for bf16 (see repro.core.quant)
         ksc, vsc = cache.get("k_scale"), cache.get("v_scale")
 
         def write(rows, valid, ring=None):
-            """Scatter the new rows into the pools; returns the written pools
-            and this layer's (K, V, K scale, V scale) for attention."""
+            """Scatter the new rows into layer ``layer`` of the pools; returns
+            the written pools and their (K, V, K scale, V scale) leaves,
+            which attention reads at ``layer`` in place."""
             w = functools.partial(
                 _paged_kv_write, rows=rows, valid=valid,
                 page_table=page_table, page=page, ring_tiles=ring, layer=layer,
@@ -375,8 +373,7 @@ def apply_attention(
             else:
                 (pk, pks), (pv, pvs) = w(kc, k_new, scale=ksc), w(vc, v_new, scale=vsc)
                 pools = {"k": pk, "v": pv, "k_scale": pks, "v_scale": pvs}
-            names = ("k", "v", "k_scale", "v_scale")
-            return pools, [_layer_of(pools.get(n), layer) for n in names]
+            return pools, [pools.get(n) for n in ("k", "v", "k_scale", "v_scale")]
 
         if mode == "mixed":
             assert ntok is not None
@@ -384,8 +381,8 @@ def apply_attention(
             valid = jnp.arange(s)[None, :] < ntok[:, None]
             new_cache, (kl, vl, ksl, vsl) = write(rows, valid, ring_tiles)
             out = run_paged_chunk_attention(
-                q, kl, vl, pos, ntok, page_table, page=page, spec=spec,
-                rt=rt, kv_live=kv_live, ring_window=ring_window,
+                q, kl, vl, pos, ntok, page_table, page=page, layer=layer,
+                spec=spec, rt=rt, kv_live=kv_live, ring_window=ring_window,
                 ring_tiles=ring_tiles, k_scale=ksl, v_scale=vsl,
             )
         elif mode == "decode":
@@ -398,8 +395,8 @@ def apply_attention(
             valid = jnp.ones_like(rows, bool)
             new_cache, (kl, vl, ksl, vsl) = write(rows, valid, ring_tiles)
             out = run_paged_decode_attention(
-                q[:, 0], kl, vl, pos + 1, page_table, page=page, spec=spec,
-                rt=rt, kv_live=kv_live, ring_window=ring_window,
+                q[:, 0], kl, vl, pos + 1, page_table, page=page, layer=layer,
+                spec=spec, rt=rt, kv_live=kv_live, ring_window=ring_window,
                 ring_tiles=ring_tiles, k_scale=ksl, v_scale=vsl,
             )[:, None]
         elif mode == "prefill":
@@ -417,8 +414,8 @@ def apply_attention(
             valid = jnp.arange(s)[None, :] < ln[:, None]
             new_cache, (kl, vl, ksl, vsl) = write(rows, valid)
             out = run_paged_prefill_attention(
-                q, k_new, v_new, kl, vl, page_table, page=page, spec=spec,
-                rt=rt, k_scale=ksl, v_scale=vsl,
+                q, k_new, v_new, kl, vl, page_table, page=page, layer=layer,
+                spec=spec, rt=rt, k_scale=ksl, v_scale=vsl,
             )
         else:
             raise ValueError(f"paged caches have no {mode!r} mode")
@@ -625,15 +622,18 @@ def run_stack(
 ):
     """Scan the periodic layer pattern.  Returns (x, new_caches, aux_sum).
 
-    Paged pools (``caches`` with a ``page_table``) ride the scan's CARRY:
-    the scan runs over ``(layer_params, layer index)``, layer ``i`` scatters
-    its new rows into the stacked pools at ``(i, row)`` and its attention
-    reads ``pool[i]``, so the pools that leave the loop are the (donated)
-    input buffers written in place — no per-layer restack into a second
-    stacked buffer and no whole-pool copy after the loop.  Read-only cross
-    pools ride the carry unwritten.  Every other cache (contiguous per-slot
-    KV, mamba state) has a batch axis and goes through the scan's
-    ``xs``/``ys``; ``cfg.unroll_layers`` slices every cache per layer."""
+    Paged pools (``caches`` with a ``page_table``) ride the scan's CARRY,
+    stacked by layer in the kernels' layout (:func:`paged_pool_specs`): the
+    scan runs over ``(layer_params, layer index)``, layer ``i`` writes its
+    new rows into the stacked pools at ``(i, :, row)`` and its attention
+    kernels read layer ``i`` where it lives, so the pools that leave the
+    loop are the (donated) input buffers written in place — no per-layer
+    restack into a second stacked buffer, no whole-pool copy after the
+    loop, and no per-call slice or transpose of a layer's pool.  Read-only
+    cross pools ride the carry unwritten.  Every other cache (contiguous
+    per-slot KV, mamba state) has a batch axis and goes through the scan's
+    ``xs``/``ys``; ``cfg.unroll_layers`` slices every cache per layer (a
+    paged pool as a one-layer stack, read at layer 0)."""
 
     def body(carry, per, layer=None):
         x, aux = carry
@@ -656,20 +656,26 @@ def run_stack(
     if cfg.remat and mode == "train":
         body = jax.checkpoint(body, policy=jax.checkpoint_policies.nothing_saveable)
 
+    paged = caches is not None and page_table is not None
     if cfg.unroll_layers:  # cost-probe mode: see ModelConfig.unroll_layers
         n = jax.tree.leaves(layer_params)[0].shape[0]
         carry = (x, jnp.zeros((), jnp.float32))
         outs = []
         for i in range(n):
             p_i = jax.tree.map(lambda a: a[i], layer_params)
-            c_i = None if caches is None else jax.tree.map(lambda a: a[i], caches)
-            carry, nc = body(carry, (p_i, c_i))
+            if paged:  # layer i's pools as a one-layer stack
+                c_i = jax.tree.map(lambda a: a[i : i + 1], caches)
+                carry, nc = body(carry, (p_i, c_i), layer=0)
+            else:
+                c_i = None if caches is None else jax.tree.map(lambda a: a[i], caches)
+                carry, nc = body(carry, (p_i, c_i))
             outs.append(nc)
         (x, aux) = carry
-        new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *outs) if outs else {}
+        join = jnp.concatenate if paged else jnp.stack
+        new_caches = jax.tree.map(lambda *xs: join(xs), *outs) if outs else {}
         return x, new_caches, aux
 
-    if caches is not None and page_table is not None:
+    if paged:
         def carried(carry, per):
             x, aux, pools = carry
             p_params, i = per
@@ -891,19 +897,22 @@ def paged_pool_specs(
     kv_dtype: str = "bf16",
 ) -> dict:
     """ParamSpec tree for the paged KV cache: one GLOBAL page pool per
-    attention slot, (n_periods, n_pages * page, KV, hd) — no batch axis, no
+    attention slot, (n_periods, KV, n_pages * page, hd) — no batch axis, no
     per-slot ``cache_len`` reservation.  Resident HBM is the pool; per-request
     footprint is the pages its page table holds, so capacity prices at live
-    tiles instead of ``batch x cache_len``.  The pools stay stacked over
-    periods through every call: :func:`run_stack` carries them through its
-    layer scan and writes layer ``i``'s rows in place at ``(i, row)``.
+    tiles instead of ``batch x cache_len``.  This is the layout the paged
+    kernels read, and the pools stay in it, stacked over periods, through
+    every call: :func:`run_stack` carries them through its layer scan, layer
+    ``i`` writes its rows in place at ``(i, :, row)`` and the kernels read
+    layer ``i`` where it lives (the layer is a scalar-prefetch operand of
+    their index maps), so no call slices or transposes a layer's pool.
     Sliding-window configs need no
     special layout here — the ring modulus lives in the page TABLE
     (mod-window slots), the pool is just pages.  Encoder-decoder stacks add a
     per-slot ``cross`` pool of ``cross_pages`` pages holding the encoder
     output's KV as read-only shared page ranges.  Pools shard KV heads over
     the model axis AND pages over the ``pages`` mesh axis: GSPMD partitions
-    the row axis contiguously, so shard ``s`` of ``k`` owns physical pages
+    the row axis (axis 2) contiguously, so shard ``s`` of ``k`` owns physical pages
     ``[s * n_pages/k, (s+1) * n_pages/k)`` — the same ranges the host-side
     :class:`repro.launch.serve.PagePool` shards its free lists over, which
     is what lets :func:`repro.core.sparsity.translate_tables` rebase a
@@ -913,9 +922,11 @@ def paged_pool_specs(
     shared, its capacity is not the scaling axis.
 
     ``kv_dtype`` != 'bf16' adds float32 ``k_scale`` / ``v_scale`` leaves
-    shaped (n_periods, n_pages * page, KV) to each self-attention pool —
-    one symmetric scale per (row, kv_head), sharded and paged exactly like
-    the rows they reconstruct (:mod:`repro.core.quant`).  Cross pools stay
+    shaped (n_periods, KV, n_pages, 1, page) to each self-attention pool —
+    one symmetric scale per (row, kv_head), a page's scales one lane row, so
+    the kernels' (1, 1, 1, 1, page) scale block has its last two dims equal
+    to the array's as the TPU tiling requires; sharded and paged exactly
+    like the rows they reconstruct (:mod:`repro.core.quant`).  Cross pools stay
     unquantized: they are written once at encode and read-only shared, so
     capacity pressure (the quantization motive) never lands on them."""
     n = cfg.n_periods
@@ -925,20 +936,22 @@ def paged_pool_specs(
         sc: dict = {}
         if slot.mixer == "attn":
             kvspec = ParamSpec(
-                (n, n_pages * page, kv, hd), (None, "pages", "tp", None)
+                (n, kv, n_pages * page, hd), (None, "tp", "pages", None)
             )
             sc["attn"] = {"k": kvspec, "v": kvspec}
             if kv_dtype != "bf16":
                 quant.validate_kv_dtype(kv_dtype)
-                sspec = ParamSpec((n, n_pages * page, kv), (None, "pages", "tp"))
+                sspec = ParamSpec(
+                    (n, kv, n_pages, 1, page), (None, "tp", "pages", None, None)
+                )
                 sc["attn"]["k_scale"] = sspec
                 sc["attn"]["v_scale"] = sspec
         elif slot.mixer == "mamba":
             raise ValueError("paged serving requires attention-only stacks")
         if cfg.family == "encdec":
             cspec = ParamSpec(
-                (n, (cross_pages or n_pages) * page, kv, hd),
-                (None, None, "tp", None),
+                (n, kv, (cross_pages or n_pages) * page, hd),
+                (None, "tp", None, None),
             )
             sc["cross"] = {"k": cspec, "v": cspec}
         out[f"slot{j:02d}"] = sc

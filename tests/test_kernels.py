@@ -78,3 +78,84 @@ def test_fnet_staged_xla_matches_reference():
     np.testing.assert_allclose(
         np.asarray(y), np.asarray(y_ref), rtol=1e-3, atol=1e-3 * float(jnp.abs(y_ref).max())
     )
+
+
+# --------------------------------------------------------------------------
+# Paged kernels read layer i of the stacked pool where it is stored
+# --------------------------------------------------------------------------
+
+PAGED_CALLS = ["decode", "chunk", "prefill", "ring_decode", "ring_chunk"]
+
+
+def _stacked_pool(kv_dtype, n_layers, kv, rows, hd, page):
+    """A stacked pool in the stored layout, every layer's rows distinct:
+    K/V (L, KV, rows, hd) and, quantized, scales (L, KV, n_pages, 1, page)."""
+    k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(5), 4)
+    shape = (n_layers, kv, rows, hd)
+    if kv_dtype == "bf16":
+        return jax.random.normal(k1, shape), jax.random.normal(k2, shape), None, None
+    sshape = (n_layers, kv, rows // page, 1, page)
+    return (
+        jax.random.randint(k1, shape, -127, 128).astype(jnp.int8),
+        jax.random.randint(k2, shape, -127, 128).astype(jnp.int8),
+        jax.random.uniform(k3, sshape, jnp.float32, 0.005, 0.02),
+        jax.random.uniform(k4, sshape, jnp.float32, 0.005, 0.02),
+    )
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("call", PAGED_CALLS)
+def test_paged_kernels_read_the_named_layer(monkeypatch, call, kv_dtype):
+    """Each paged kernel, handed the whole stacked pool and layer ``i``,
+    reads layer ``i`` and nothing else: its output equals, bit for bit, the
+    same call on a one-layer stack holding layer ``i`` alone, and differs
+    from layer to layer.  Interpret mode, with the pool passed to the
+    kernel stacked, as the TPU path passes it (on the CPU ``ops`` otherwise
+    hands the interpreter the one layer it reads)."""
+    from repro.core.attention import AttentionSpec
+
+    monkeypatch.setattr(ops, "_kernel_pools", lambda layer, *leaves: (layer, leaves))
+    n_layers, kv, h, hd, page, n_pages = 3, 2, 4, 64, 16, 8
+    kp, vp, ks, vs = _stacked_pool(kv_dtype, n_layers, kv, n_pages * page, hd, page)
+    spec = AttentionSpec(impl="flash_kernel")
+    q = jax.random.normal(jax.random.PRNGKey(6), (2, 3 * page, h, hd))
+    # row 0 owns pages 5, 2, 7, 0; row 1 pages 1, 6, 3, 4
+    pt = jnp.asarray([[5, 2, 7, 0], [1, 6, 3, 4]], jnp.int32)
+    ring = dict(ring_window=20, ring_tiles=4)
+
+    def run(k, v, k_scale, v_scale, layer):
+        sc = dict(page=page, spec=spec, layer=layer, k_scale=k_scale, v_scale=v_scale)
+        if call == "decode":
+            return ops.flash_paged_decode(
+                q[:, 0], k, v, jnp.asarray([61, 30], jnp.int32), pt, **sc
+            )
+        if call == "ring_decode":  # positions past the ring's span
+            return ops.flash_paged_decode(
+                q[:, 0], k, v, jnp.asarray([150, 97], jnp.int32), pt, **sc,
+                kv_live=160, **ring,
+            )
+        if call == "chunk":
+            return ops.flash_paged_chunk(
+                q[:, :page], k, v, jnp.asarray([40, 9], jnp.int32),
+                jnp.asarray([page, 5], jnp.int32), pt, **sc,
+            )
+        if call == "ring_chunk":
+            return ops.flash_paged_chunk(
+                q[:, :page], k, v, jnp.asarray([120, 70], jnp.int32),
+                jnp.asarray([page, 7], jnp.int32), pt, **sc, kv_live=144, **ring,
+            )
+        return ops.flash_paged_prefill(q[:1], k, v, pt[0], **sc)  # 3 pages
+
+    def one(a, i):
+        return None if a is None else a[i : i + 1]
+
+    outs = []
+    for i in range(n_layers):
+        got = run(kp, vp, ks, vs, i)
+        want = run(one(kp, i), one(vp, i), one(ks, i), one(vs, i), 0)
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(want), err_msg=f"layer {i}"
+        )
+        outs.append(np.asarray(got))
+    for i in range(1, n_layers):
+        assert not np.array_equal(outs[0], outs[i])

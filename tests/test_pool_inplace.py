@@ -3,8 +3,9 @@
 Two contracts of :func:`repro.models.transformer.run_stack` on the paged path:
 
 * no compiled paged program (decode wave, prompt chunk, admission prefill)
-  materialises a second buffer of the stacked pool's shape ``[L, rows, ...]``
-  — no per-layer restack into a fresh stacked buffer (``dynamic-update-slice``
+  materialises a second buffer of a stacked pool leaf's shape (K/V ``[L,
+  KV, rows, hd]``, scales ``[L, KV, n_pages, 1, page]``) — no per-layer
+  restack into a fresh stacked buffer (``dynamic-update-slice``
   into an ``AllocateBuffer``), no ``broadcast`` to seed one, no whole-pool
   ``copy`` after the loop.  The new rows scatter straight into the donated
   pool.
@@ -12,9 +13,10 @@ Two contracts of :func:`repro.models.transformer.run_stack` on the paged path:
   (``cfg.unroll_layers``: slice layer i's pool, write it, restack) computes:
   the same logits and the same bytes in every pool leaf at every layer.
 
-:func:`stacked_pool_ops` and :func:`paged_lowerings` are shared with
-``test_chip_compile.py``, which asserts the first contract for a described
-TPU v5e.
+:func:`stacked_pool_ops`, :func:`layer_pool_ops` and :func:`paged_lowerings`
+are shared with ``test_chip_compile.py``, which asserts the first contract
+for a described TPU v5e, and there also that no program makes a buffer of
+one layer's pool: the kernels read layer ``i`` where it is stored.
 """
 
 from __future__ import annotations
@@ -36,17 +38,18 @@ from repro.models import model as M
 from repro.models import transformer as tf
 
 PAGE = 128
+KV, HD = 8, 128  # qwen3-0.6b's kv heads and head width
 N_VT = 8  # virtual tiles per page-table row: a 1,024-token cache
 KV_LIVE = N_VT * PAGE
 
 
 def stacked_pool_ops(hlo: str, *dims: int) -> list[str]:
     """Instructions of an optimized HLO module that make a new buffer of a
-    stacked pool leaf's shape, a shape that starts with ``dims`` (``L,
-    rows`` matches the K/V leaves and the scale leaves, ``L, rows, KV, hd``
-    the K/V leaves alone): ``copy`` / ``copy-start``, ``broadcast``,
-    ``dynamic-update-slice`` and custom calls (``AllocateBuffer``).  An
-    in-place scatter into the carried pool is none of these."""
+    stacked pool leaf's shape, a shape that starts with ``dims`` (``L, KV,
+    rows`` matches the K/V leaves, ``L, KV, n_pages, 1`` the scale leaves):
+    ``copy`` / ``copy-start``, ``broadcast``, ``dynamic-update-slice`` and
+    custom calls (``AllocateBuffer``).  An in-place scatter into the carried
+    pool is none of these."""
     op = re.compile(
         r"= \w+\[%s(,\d+)*\]\S* "
         r"(copy|copy-start|broadcast|dynamic-update-slice|custom-call)\("
@@ -55,13 +58,33 @@ def stacked_pool_ops(hlo: str, *dims: int) -> list[str]:
     return [ln.strip()[:200] for ln in hlo.splitlines() if op.search(ln)]
 
 
+def layer_pool_ops(hlo: str, rows: int, kv: int, hd: int) -> list[str]:
+    """Instructions of an optimized HLO module that make a buffer of ONE
+    layer's K/V pool, in the stored layout or the one before it: ``[1, KV,
+    rows, hd]`` / ``[KV, rows, hd]`` (a layer of the stack, as a kernel
+    reads it) or ``[1, rows, KV, hd]`` / ``[rows, KV, hd]`` — made by a
+    ``(dynamic-)slice``, ``copy``, ``transpose`` or fusion, or the end of an
+    async slice or copy.  A kernel that reads the stacked pool in place
+    makes none."""
+    shapes = ((1, kv, rows, hd), (kv, rows, hd), (1, rows, kv, hd), (rows, kv, hd))
+    op = re.compile(
+        r"= \w+\[(%s)\]\S* "
+        r"(dynamic-slice|slice|slice-done|copy|copy-done|transpose|fusion)\("
+        % "|".join(",".join(map(str, s)) for s in shapes)
+    )
+    return [ln.strip()[:200] for ln in hlo.splitlines() if op.search(ln)]
+
+
 def paged_lowerings(cfg, kv_dtype: str, n_pages: int, *, chunk: int,
-                    batch: int, prompt: int, device=None) -> dict:
+                    batch: int, prompt: int, device=None, mesh=None) -> dict:
     """``{program: thunk}``: each thunk lowers one of the paged entry points
     (decode wave, prompt chunk, admission prefill) of ``make_paged_fns`` on
-    abstract parameters and pools, committed to ``device`` when given."""
-    devices = [device] if device is not None else jax.devices()[:1]
-    mesh = make_mesh((1, 1), ("data", "model"), devices=devices)
+    abstract parameters and pools, committed to ``device`` when given, or
+    laid out over ``mesh`` (a ``pages`` axis shards the pool) by the entry
+    points' own shardings."""
+    if mesh is None:
+        devices = [device] if device is not None else jax.devices()[:1]
+        mesh = make_mesh((1, 1), ("data", "model"), devices=devices)
     pre, dec, chk, _, _ = make_paged_fns(
         cfg, mesh, n_pages=n_pages, page=PAGE, chunk=chunk, kv_dtype=kv_dtype
     )
@@ -117,17 +140,18 @@ def test_paged_programs_write_pool_in_place(kv_dtype, program):
     )[program]
     hlo = lower().compile().as_text()
     assert "scatter" in hlo
-    assert stacked_pool_ops(hlo, N_LAYERS, N_PAGES * PAGE) == []
+    assert stacked_pool_ops(hlo, N_LAYERS, KV, N_PAGES * PAGE) == []
+    assert stacked_pool_ops(hlo, N_LAYERS, KV, N_PAGES, 1) == []
 
 
 def test_stacked_pool_ops_finds_restack_and_copies():
     """The matcher sees the restack and the whole-pool copies it guards
     against: a scan that passes a stacked pool through ``xs``/``ys``."""
-    pool = jnp.zeros((2, 256, 2, 128), jnp.bfloat16)
+    pool = jnp.zeros((2, 2, 256, 128), jnp.bfloat16)
 
     def restack(pool, x):
         def body(c, p):
-            p = p.at[0].set(c)
+            p = p.at[:, 0].set(c)
             return c, p
 
         return jax.lax.scan(body, x, pool)[1]
@@ -138,7 +162,37 @@ def test_stacked_pool_ops_finds_restack_and_copies():
         .compile()
         .as_text()
     )
-    assert stacked_pool_ops(hlo, 2, 256)
+    assert stacked_pool_ops(hlo, 2, 2, 256)
+
+
+def test_layer_pool_ops_finds_layer_slices():
+    """The one-layer matcher sees the per-call layer moves it guards
+    against: the slice and transpose ops of the pool layout the kernels no
+    longer need (as the optimized TPU modules named them), and a compiled
+    slice of one layer out of a stacked pool; not the in-place scatter or
+    a bitcast of the stacked pool."""
+    rows, t = 36864, "{3,2,1,0:T(8,128)(2,1)}"
+    lines = [
+        f"%constant_dynamic-slice_fusion.14 = bf16[1,{rows},8,128]{t} "
+        "fusion(%p0, %i), kind=kLoop",
+        f"%copy_bitcast_fusion.4 = bf16[8,{rows},128]{{2,1,0}} fusion(%a), kind=kLoop",
+        f"%slice-done.2 = bf16[1,8,{rows},128]{t} slice-done(%slice-start.2)",
+        f"%scatter.3 = bf16[28,8,{rows},128]{t} scatter(%p, %i, %u), "
+        "update_window_dims={2}",
+        f"%bitcast.5 = bf16[28,8,{rows},128]{t} bitcast(%f)",
+    ]
+    found = layer_pool_ops("\n".join(lines), rows, 8, 128)
+    assert [ln.split(" =")[0] for ln in found] == [
+        "%constant_dynamic-slice_fusion.14", "%copy_bitcast_fusion.4",
+        "%slice-done.2",
+    ]
+
+    def one_layer(pool, i):
+        return jax.lax.dynamic_index_in_dim(pool, i, keepdims=False) * 2
+
+    pool = jnp.zeros((3, 2, 256, 128), jnp.bfloat16)
+    hlo = jax.jit(one_layer).lower(pool, 1).compile().as_text()
+    assert layer_pool_ops(hlo, 256, 2, 128)
 
 
 # --------------------------------------------------------------------------
@@ -231,3 +285,4 @@ def test_carried_pool_matches_sliced_reference(kv_dtype, pattern):
                 # the call wrote this layer (not a vacuous match of two
                 # untouched pools)
                 assert not np.array_equal(got, _bits(old[path][i]))
+

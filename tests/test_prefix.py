@@ -132,12 +132,15 @@ def test_gather_pages_aliased_tables_parity():
 
     page, n_pages, KV, hd = 4, 6, 2, 3
     rng = np.random.default_rng(0)
-    pool = jnp.asarray(rng.normal(size=(n_pages * page, KV, hd)).astype(np.float32))
+    # a one-layer stack in the stored layout (L, KV, rows, hd)
+    pool = jnp.asarray(
+        rng.normal(size=(1, KV, n_pages * page, hd)).astype(np.float32)
+    )
     # rows 0 and 1 share physical page 2 for vtile 0; diverge on vtile 1
     aliased = jnp.asarray(np.array([[2, 0], [2, 1]], np.int32))
     private = jnp.asarray(np.array([[2, 0], [3, 1]], np.int32))
     # make the "private copy" page 3 hold the same values as shared page 2
-    pool_priv = pool.at[3 * page:4 * page].set(pool[2 * page:3 * page])
+    pool_priv = pool.at[:, :, 3 * page:4 * page].set(pool[:, :, 2 * page:3 * page])
     out_a = np.asarray(gather_pages(pool, aliased, 2 * page, page))
     out_p = np.asarray(gather_pages(pool_priv, private, 2 * page, page))
     # shared vtile 0 rows identical across the two rows of the aliased table

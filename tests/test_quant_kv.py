@@ -121,25 +121,34 @@ def test_paged_copy_page_carries_scales():
     page, n_pages, kv, hd = 4, 3, 2, 8
     rows = n_pages * page
     key = jax.random.PRNGKey(1)
+    # stacked pool leaves as stored: K/V (L, KV, rows, hd), scales (L, KV,
+    # n_pages, 1, page)
     caches = {
         "slot00": {
             "attn": {
-                "k": jax.random.normal(key, (1, rows, kv, hd)),
-                "v": jax.random.normal(key, (1, rows, kv, hd)),
-                "k_scale": jax.random.uniform(key, (1, rows, kv)) + 0.5,
-                "v_scale": jax.random.uniform(key, (1, rows, kv)) + 0.5,
+                "k": jax.random.normal(key, (2, kv, rows, hd)),
+                "v": jax.random.normal(key, (2, kv, rows, hd)),
+                "k_scale": jax.random.uniform(key, (2, kv, n_pages, 1, page)) + 0.5,
+                "v_scale": jax.random.uniform(key, (2, kv, n_pages, 1, page)) + 0.5,
             }
         }
     }
+
+    def page_of(leaf, p):  # every layer's and head's rows of page p
+        leaf = np.asarray(leaf)
+        if leaf.ndim == 5:
+            return leaf[:, :, p]
+        return leaf[:, :, p * page:(p + 1) * page]
+
     out = tf.paged_copy_page(caches, jnp.int32(0), jnp.int32(2), page)
     for name in ("k", "v", "k_scale", "v_scale"):
-        src = caches["slot00"]["attn"][name][:, 0 * page:1 * page]
-        dst = out["slot00"]["attn"][name][:, 2 * page:3 * page]
-        np.testing.assert_array_equal(np.asarray(src), np.asarray(dst), name)
+        src = page_of(caches["slot00"]["attn"][name], 0)
+        dst = page_of(out["slot00"]["attn"][name], 2)
+        np.testing.assert_array_equal(src, dst, name)
         # untouched pages stay untouched
         np.testing.assert_array_equal(
-            np.asarray(caches["slot00"]["attn"][name][:, page:2 * page]),
-            np.asarray(out["slot00"]["attn"][name][:, page:2 * page]),
+            page_of(caches["slot00"]["attn"][name], 1),
+            page_of(out["slot00"]["attn"][name], 1),
         )
 
 
@@ -154,7 +163,8 @@ def test_pool_specs_and_zero_pools_dtypes():
     for slot, sc in q8.items():
         assert set(sc["attn"]) == {"k", "v", "k_scale", "v_scale"}
         assert set(base[slot]["attn"]) == {"k", "v"}
-        assert sc["attn"]["k_scale"].shape == sc["attn"]["k"].shape[:-1]
+        # one scale per (layer, kv head, row), a page's scales one lane row
+        assert sc["attn"]["k_scale"].shape == (*sc["attn"]["k"].shape[:2], 4, 1, 8)
     with pytest.raises(ValueError, match="kv_dtype"):
         tf.paged_pool_specs(cfg, 4, 8, kv_dtype="int4")
 
@@ -218,14 +228,15 @@ def _build_pool(k_full, v_full, page, kv_dtype):
     )
     rows = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None, :], (b, s))
     valid = jnp.ones((b, s), bool)
-    kp = jnp.zeros((n_pages * page, kv, hd), store)
-    vp = jnp.zeros((n_pages * page, kv, hd), store)
+    # one-layer stacks in the stored layout (read at layer 0)
+    kp = jnp.zeros((1, kv, n_pages * page, hd), store)
+    vp = jnp.zeros((1, kv, n_pages * page, hd), store)
     if kv_dtype == "bf16":
         kp = tf._paged_kv_write(kp, k_full, rows, valid, pt, page)
         vp = tf._paged_kv_write(vp, v_full, rows, valid, pt, page)
         return kp, vp, None, None, pt
-    ks = jnp.zeros((n_pages * page, kv), jnp.float32)
-    vs = jnp.zeros((n_pages * page, kv), jnp.float32)
+    ks = jnp.zeros((1, kv, n_pages, 1, page), jnp.float32)
+    vs = jnp.zeros((1, kv, n_pages, 1, page), jnp.float32)
     kp, ks = tf._paged_kv_write(kp, k_full, rows, valid, pt, page, scale=ks)
     vp, vs = tf._paged_kv_write(vp, v_full, rows, valid, pt, page, scale=vs)
     return kp, vp, ks, vs, pt

@@ -171,6 +171,9 @@ def test_ring_gather_decode_matches_masked_oracle_gqa():
         pool_v[r] = np.asarray(v_full[0, p])
 
     pt = jnp.arange(R, dtype=jnp.int32)[None, :]
+    # the stored layout, a one-layer stack (L, KV, rows, hd)
+    pool_k = pool_k.transpose(1, 0, 2)[None]
+    pool_v = pool_v.transpose(1, 0, 2)[None]
     kg = layers.gather_pages(jnp.asarray(pool_k), pt, R * page, page)
     vg = layers.gather_pages(jnp.asarray(pool_v), pt, R * page, page)
     kpos = layers.ring_kpos(jnp.asarray([L - 1]), page, R)

@@ -98,20 +98,23 @@ def test_sharded_gather_reassembles_replicated(n_shards, kv_heads):
     rng = np.random.default_rng(7)
     B, page, n_vtiles, hd = 3, 16, 6, 8
     n_pages = ((B * n_vtiles + 2) // n_shards + 1) * n_shards
+    # a two-layer stack in the stored layout (L, KV, rows, hd), read at
+    # layer 1; a shard holds every layer's rows of its page range
     pool = jnp.asarray(
-        rng.normal(size=(n_pages * page, kv_heads, hd)).astype(np.float32)
+        rng.normal(size=(2, kv_heads, n_pages * page, hd)).astype(np.float32)
     )
     pt = _random_tables(rng, B, n_vtiles, n_pages)
     n_rows = n_vtiles * page
-    rep = np.asarray(gather_pages(pool, jnp.asarray(pt), n_rows, page))
+    rep = np.asarray(gather_pages(pool, jnp.asarray(pt), n_rows, page, layer=1))
     pps = n_pages // n_shards
     acc = np.zeros_like(rep)
     for s in range(n_shards):
         lo, hi = s * pps, (s + 1) * pps
-        local = pool[lo * page : hi * page]
+        local = pool[:, :, lo * page : hi * page]
         acc += np.asarray(
             gather_pages(
-                local, jnp.asarray(pt), n_rows, page, page_range=(lo, hi)
+                local, jnp.asarray(pt), n_rows, page, page_range=(lo, hi),
+                layer=1,
             )
         )
     alloc_rows = pt[:, np.arange(n_rows) // page] != n_pages  # (B, n_rows)
